@@ -270,15 +270,25 @@ def _model_scale_de_vs_optda(oracle_budget: int = 16):
                   for m, (s, l) in results.items()))
 
 
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _cpu_child_env() -> dict:
+    """Environment of a train-CLI child simulating 8 workers on forced
+    host devices.  It runs on the CPU: this process may hold the
+    accelerator, and a device belongs to one process at a time."""
+    src = os.path.join(_ROOT, "src")
+    pp = os.environ.get("PYTHONPATH")
+    return {**os.environ, "JAX_PLATFORMS": "cpu",
+            "PYTHONPATH": src + os.pathsep + pp if pp else src}
+
+
 def _sync_every_tradeoff(steps: int = 16):
     """Wire/quality trade-off of the local-update regime: total measured
     wire_bytes (the metric == trace recorder, see tests) and final loss
     at sync_every in {1, 4, 16} on 8 forced host devices (subprocess —
     this process stays single-device)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(root, "src")
-    pp = os.environ.get("PYTHONPATH")
-    env = {**os.environ, "PYTHONPATH": src + os.pathsep + pp if pp else src}
+    env = _cpu_child_env()
     rows = []
     for sync in (1, 4, 16):
         r = subprocess.run(
@@ -288,7 +298,7 @@ def _sync_every_tradeoff(steps: int = 16):
              "--repeat-batch", "--optimizer", "qgenx",
              "--gamma-scale", "0.02", "--compression", "int8",
              "--compress-axis", "data", "--sync-every", str(sync)],
-            cwd=root, env=env, capture_output=True, text=True, timeout=900,
+            cwd=_ROOT, env=env, capture_output=True, text=True, timeout=900,
         )
         if r.returncode != 0:
             emit(f"sync_every{sync}_wire_quality", 0.0,
@@ -312,10 +322,7 @@ def _recenter_tradeoff(steps: int = 16):
     sync_every=4 with recenter_every in {0, 8, 4} (8 forced host devices,
     subprocess) — total wire_bytes, final loss, and the drift reported on
     the last sync step."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(root, "src")
-    pp = os.environ.get("PYTHONPATH")
-    env = {**os.environ, "PYTHONPATH": src + os.pathsep + pp if pp else src}
+    env = _cpu_child_env()
     for rc in (0, 8, 4):
         r = subprocess.run(
             [sys.executable, "-m", "repro.launch.train",
@@ -325,7 +332,7 @@ def _recenter_tradeoff(steps: int = 16):
              "--gamma-scale", "0.02", "--compression", "int8",
              "--compress-axis", "data", "--sync-every", "4",
              "--recenter-every", str(rc)],
-            cwd=root, env=env, capture_output=True, text=True, timeout=900,
+            cwd=_ROOT, env=env, capture_output=True, text=True, timeout=900,
         )
         if r.returncode != 0:
             emit(f"recenter_every{rc}_drift_wire", 0.0,
@@ -348,10 +355,7 @@ def _error_feedback_model_scale(steps: int = 12):
     8k-byte wire bill per exchange — the per-step wire is cross-checked
     in the derived row) on the reduced LM through the train CLI, 8 forced
     host devices (subprocess — this process stays single-device)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(root, "src")
-    pp = os.environ.get("PYTHONPATH")
-    env = {**os.environ, "PYTHONPATH": src + os.pathsep + pp if pp else src}
+    env = _cpu_child_env()
     for tag, extra in (
         ("ef21_topk", ["--compressor", "ef21-topk", "--ef-topk-frac", "0.1"]),
         ("randk", ["--compressor", "randk", "--rand-frac", "0.1"]),
@@ -362,7 +366,7 @@ def _error_feedback_model_scale(steps: int = 12):
              "--steps", str(steps), "--batch", "16", "--seq", "32",
              "--repeat-batch", "--optimizer", "qgenx",
              "--gamma-scale", "0.02", "--compress-axis", "data"] + extra,
-            cwd=root, env=env, capture_output=True, text=True, timeout=900,
+            cwd=_ROOT, env=env, capture_output=True, text=True, timeout=900,
         )
         if r.returncode != 0:
             emit(f"model_scale_{tag}_equal_wire", 0.0,

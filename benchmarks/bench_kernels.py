@@ -246,9 +246,10 @@ def run():
         # the pallas variant's jaxpr proves the launch count at trace time
         ex_pl = make_exchange(dataclasses.replace(
             plan_cfg, use_plan=use_plan, use_pallas=True))
+        # (a kernel is staged once per platform branch; count the TPU's)
         pallas_calls = str(jax.make_jaxpr(
             lambda t, k: ex_pl.compress_tree(t, k))(tree, key)
-        ).count("pallas_call")
+        ).count("interpret=False")
         emit(f"compress_tree_{tag}_{n_tree}", us,
              f"quantize_invocations={launches};leaves={n_leaves};"
              f"pallas_calls={pallas_calls}")

@@ -6,12 +6,13 @@ paper's compressed data-parallel exchange (two-phase int8), and verifies
 the loss trajectory matches full-precision training.
 
 Run: PYTHONPATH=src python examples/train_lm.py [--steps 200]
-(thin wrapper over repro.launch.train — the production driver)
+(thin wrapper over repro.launch.train — the production driver — called
+in this process, which then owns whatever device it trains on)
 """
 
 import argparse
-import subprocess
-import sys
+
+from repro.launch import train
 
 
 def main():
@@ -23,7 +24,6 @@ def main():
     ap.add_argument("--level-schedule", default="fixed", choices=("fixed", "qada"))
     args = ap.parse_args()
     cmd = [
-        sys.executable, "-m", "repro.launch.train",
         "--arch", "tinyllama-1.1b", "--reduced",
         "--host-devices", "8",
         "--steps", str(args.steps),
@@ -37,8 +37,8 @@ def main():
     ]
     if args.level_schedule == "qada":
         cmd += ["--level-update-every", "10"]
-    print("+", " ".join(cmd))
-    raise SystemExit(subprocess.call(cmd))
+    print("+ repro.launch.train", " ".join(cmd))
+    train.main(cmd)
 
 
 if __name__ == "__main__":

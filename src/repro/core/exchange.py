@@ -1,8 +1,8 @@
 """Unified Exchange API — the single seam for Algorithm 1's communication.
 
 Everything the repo previously threaded by hand through ``compressed_pmean*``
-call sites — ``(levels, key, cfg, mode, use_pallas, use_device_prng,
-interpret)`` — is captured once in an :class:`ExchangeConfig` (frozen,
+call sites — ``(levels, key, cfg, mode, use_pallas, use_device_prng)`` —
+is captured once in an :class:`ExchangeConfig` (frozen,
 hashable, safe as a jit static argument), and :func:`make_exchange` returns
 an :class:`Exchange` whose methods are usable inside ``shard_map``:
 
@@ -302,20 +302,34 @@ def expected_index_pmf(u: Array, levels: Array) -> Array:
     and small) rather than a scatter-add: this runs inside the train
     step's shard_map, and XLA's SPMD partitioner cannot lower scatter
     under a partially-manual mesh (the same class of lowering limit that
-    forces ``ModelConfig.unroll_scan`` there).
+    forces ``ModelConfig.unroll_scan`` there).  Each reduction reads only
+    ``u`` and scalar levels — the bracket of :func:`_bracket_select`
+    spelled as two compares per symbol — so no per-coordinate bracket
+    index is materialised: over a model's gradient those intermediates
+    would outgrow the device.
     """
     lv = levels.astype(jnp.float32)
     num_symbols = lv.shape[0]
+    top = num_symbols - 2  # last bracket [l_top, l_top+1] holds u == 1
     u = u.reshape(-1)
-    tau, _, _, xi = _bracket_select(u, lv)
-    xi = jnp.clip(xi, 0.0, 1.0)
-    down, up = 1.0 - xi, xi
-    pmf = jnp.stack([
-        jnp.sum(jnp.where(tau == j, down, 0.0))
-        + jnp.sum(jnp.where(tau + 1 == j, up, 0.0))
-        for j in range(num_symbols)
-    ])
-    return pmf / u.shape[0]
+
+    def in_bracket(t):
+        lower = u >= lv[t] if t > 0 else True
+        upper = u < lv[t + 1] if t < top else True
+        return jnp.logical_and(lower, upper)
+
+    def xi(t):
+        return jnp.clip((u - lv[t]) / (lv[t + 1] - lv[t]), 0.0, 1.0)
+
+    pmf = []
+    for j in range(num_symbols):
+        mass = jnp.float32(0.0)
+        if j <= top:  # rounded down from bracket j
+            mass += jnp.sum(jnp.where(in_bracket(j), 1.0 - xi(j), 0.0))
+        if j > 0:  # rounded up from bracket j - 1
+            mass += jnp.sum(jnp.where(in_bracket(j - 1), xi(j - 1), 0.0))
+        pmf.append(mass)
+    return jnp.stack(pmf) / u.shape[0]
 
 
 def theorem2_bits_traced(pmf: Array, d, num_buckets) -> Array:
@@ -350,7 +364,6 @@ def _quantize_2d(
     use_pallas: bool,
     *,
     use_device_prng: bool = False,
-    interpret: bool = True,
 ):
     """[nb, bucket] f32 -> (wire payload [nb, P], norms [nb]).
 
@@ -371,29 +384,25 @@ def _quantize_2d(
         return quantize_blocks(
             x2d, None, levels,
             num_symbols=cfg.num_symbols, q_is_inf=q_is_inf, bits=cfg.bits,
-            use_device_prng=True, seed=seed, interpret=interpret,
+            use_device_prng=True, seed=seed,
         )
     noise = jax.random.uniform(key, x2d.shape, dtype=jnp.float32)
     if use_pallas:
         return quantize_blocks(
             x2d, noise, levels,
             num_symbols=cfg.num_symbols, q_is_inf=q_is_inf, bits=cfg.bits,
-            interpret=interpret,
         )
     from repro.kernels.ref import quantize_blocks_ref
 
     return quantize_blocks_ref(x2d, noise, levels, q_is_inf=q_is_inf, bits=cfg.bits)
 
 
-def _dequantize_2d(
-    payload2d, norms, levels, cfg: QuantConfig, use_pallas: bool,
-    *, interpret: bool = True,
-):
+def _dequantize_2d(payload2d, norms, levels, cfg: QuantConfig,
+                   use_pallas: bool):
     """Wire payload [nb, P] -> [nb, bucket] f32 (unpacks in 4-bit mode)."""
     if use_pallas:
         return dequantize_blocks(
             payload2d, norms, levels, num_symbols=cfg.num_symbols, bits=cfg.bits,
-            interpret=interpret,
         )
     from repro.kernels.ref import dequantize_blocks_ref
 
@@ -433,15 +442,13 @@ def _qgenx_pmean(
     mode: str = "two_phase",
     use_pallas: bool = False,
     use_device_prng: bool = False,
-    interpret: bool = True,
     axis_index=None,
 ) -> Array:
     """Unbiased quantized mean-reduction of a flat vector over ``axis_name``.
 
     Must be called inside shard_map with ``axis_name`` in scope. ``x`` is
     each device's local full vector (e.g. its data-parallel gradient).
-    ``interpret=False`` compiles the Pallas kernels (real TPU); the default
-    interpret mode is for this CPU container.  ``axis_index`` (optional)
+    ``axis_index`` (optional)
     supplies the device's position on partially-manual meshes where
     ``lax.axis_index`` cannot lower (see :func:`_axis_key`).
     """
@@ -456,7 +463,7 @@ def _qgenx_pmean(
         x2d, _ = _pad_to_buckets(x, bucket)
         payload, norms = _quantize_2d(
             x2d, levels, k1, cfg, use_pallas,
-            use_device_prng=use_device_prng, interpret=interpret,
+            use_device_prng=use_device_prng,
         )
         _record_wire("gather_payload", payload)
         _record_wire("gather_norms", norms)
@@ -469,13 +476,12 @@ def _qgenx_pmean(
             mean2d = dequant_reduce_blocks(
                 all_p, all_norms, levels,
                 num_symbols=cfg.num_symbols, num_workers=axis_size, bits=cfg.bits,
-                interpret=interpret,
             )
             return mean2d.reshape(-1)[:n]
         deq = _dequantize_2d(
             all_p.reshape(axis_size * nb, -1),
             all_norms.reshape(axis_size * nb),
-            levels, cfg, use_pallas, interpret=interpret,
+            levels, cfg, use_pallas,
         ).reshape(axis_size, nb * bucket)
         return jnp.mean(deq, axis=0)[:n]
 
@@ -489,7 +495,7 @@ def _qgenx_pmean(
         x2d = xp.reshape(axis_size * nb_per_chunk, bucket)
         payload, norms = _quantize_2d(
             x2d, levels, k1, cfg, use_pallas,
-            use_device_prng=use_device_prng, interpret=interpret,
+            use_device_prng=use_device_prng,
         )
         # [K, nb_per_chunk, P] — row k is the chunk destined to device k
         payload = payload.reshape(axis_size, nb_per_chunk, -1)
@@ -512,26 +518,25 @@ def _qgenx_pmean(
                 p_t, n_t, levels, noise2,
                 num_symbols=cfg.num_symbols, num_workers=axis_size,
                 q_is_inf=math.isinf(cfg.q_norm), bits=cfg.bits,
-                use_device_prng=use_device_prng, seed=seed2, interpret=interpret,
+                use_device_prng=use_device_prng, seed=seed2,
             )
         else:
             deq = _dequantize_2d(
                 p_t.reshape(axis_size * nb_per_chunk, -1),
                 n_t.reshape(axis_size * nb_per_chunk),
-                levels, cfg, use_pallas, interpret=interpret,
+                levels, cfg, use_pallas,
             ).reshape(axis_size, chunk)
             reduced = jnp.mean(deq, axis=0)  # this device's chunk of the mean
             # re-quantize (unbiased) and share the reduced chunk
             r2d = reduced.reshape(nb_per_chunk, bucket)
             ridx, rnorms = _quantize_2d(
-                r2d, levels, k2, cfg, use_pallas, interpret=interpret
+                r2d, levels, k2, cfg, use_pallas
             )
         _record_wire("gather_payload", ridx)
         _record_wire("gather_norms", rnorms)
         g_idx = jax.lax.all_gather(ridx, axis_name, tiled=True)
         g_norms = jax.lax.all_gather(rnorms, axis_name, tiled=True)
-        out = _dequantize_2d(g_idx, g_norms, levels, cfg, use_pallas,
-                             interpret=interpret)
+        out = _dequantize_2d(g_idx, g_norms, levels, cfg, use_pallas)
         return out.reshape(-1)[:n]
 
     raise ValueError(f"unknown mode {mode!r}")
@@ -666,7 +671,7 @@ class ExchangeConfig:
       mode: "gather" | "two_phase" | "leafwise" (tree exchanges; flat
         ``pmean`` accepts gather/two_phase).
       axis_name: the shard_map axis the exchange reduces over.
-      use_pallas / use_device_prng / interpret: kernel routing flags
+      use_pallas / use_device_prng: kernel routing flags
         (previously dropped on the floor between the train step and the
         exchange — now carried here so every consumer forwards them).
       level_schedule: "fixed" | "qada" — QAda (Section 3.3) accumulates
@@ -764,7 +769,6 @@ class ExchangeConfig:
     axis_name: str = "data"
     use_pallas: bool = False
     use_device_prng: bool = False
-    interpret: bool = True
     level_schedule: str = "fixed"
     level_update_every: int = 0
     qada_bins: int = 512
@@ -1239,10 +1243,15 @@ class NoneCompressor(Compressor):
     name = "none"
 
     def pmean(self, x, cfg, state, key, axis_index=None):
-        return jax.lax.pmean(x, cfg.axis_name)
+        return self.pmean_tree(x, cfg, state, key, axis_index)
 
     def pmean_tree(self, tree, cfg, state, key, axis_index=None):
-        return jax.lax.pmean(tree, cfg.axis_name)
+        # the mean is taken in f32 (what wire_bytes prices), whatever the
+        # leaves' dtype; a bf16 all-reduce also crashes XLA:CPU's
+        # all-reduce promotion inside a partially-manual shard_map
+        return jax.tree_util.tree_map(
+            lambda g: jax.lax.pmean(g.astype(jnp.float32), cfg.axis_name)
+            .astype(g.dtype), tree)
 
     def compress(self, v, cfg, levels, key):
         return v
@@ -1290,7 +1299,7 @@ class QgenxCompressor(Compressor):
             raise ValueError("mode='leafwise' is a tree exchange; use pmean_tree")
         return _qgenx_pmean(
             x, cfg.axis_name, state.levels, key, self._quant(cfg), cfg.mode,
-            cfg.use_pallas, cfg.use_device_prng, cfg.interpret,
+            cfg.use_pallas, cfg.use_device_prng,
             axis_index=axis_index,
         )
 
@@ -1324,7 +1333,6 @@ class QgenxCompressor(Compressor):
         hat = xplan.fused_compress(
             plan, plan.pack(leaves), (lv,) * len(plan.segments), key,
             use_pallas=cfg.use_pallas, use_device_prng=cfg.use_device_prng,
-            interpret=cfg.interpret,
         )
         return jax.tree_util.tree_unflatten(treedef, plan.unpack(hat, leaves))
 
@@ -1653,7 +1661,7 @@ class LayerwiseCompressor(Compressor):
             outs.append(_qgenx_pmean(
                 flat[seg.start: seg.stop], cfg.axis_name, levels,
                 jax.random.fold_in(key, seg.key_tag), seg.quant, cfg.mode,
-                cfg.use_pallas, cfg.use_device_prng, cfg.interpret,
+                cfg.use_pallas, cfg.use_device_prng,
                 axis_index=axis_index,
             ))
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
@@ -1666,7 +1674,7 @@ class LayerwiseCompressor(Compressor):
         levels = state.levels_lo if big else state.levels
         return _qgenx_pmean(
             x, cfg.axis_name, levels, key, qcfg, cfg.mode,
-            cfg.use_pallas, cfg.use_device_prng, cfg.interpret,
+            cfg.use_pallas, cfg.use_device_prng,
             axis_index=axis_index,
         )
 
@@ -1692,7 +1700,7 @@ class LayerwiseCompressor(Compressor):
             )
             mean = _qgenx_pmean(
                 flat, cfg.axis_name, levels, jax.random.fold_in(key, gid),
-                qcfg, mode, cfg.use_pallas, cfg.use_device_prng, cfg.interpret,
+                qcfg, mode, cfg.use_pallas, cfg.use_device_prng,
                 axis_index=axis_index,
             )
             for i, o in zip(idxs, _split_like(mean, group)):
@@ -1730,7 +1738,6 @@ class LayerwiseCompressor(Compressor):
         hat = xplan.fused_compress(
             plan, plan.pack(leaves), tables, key,
             use_pallas=cfg.use_pallas, use_device_prng=cfg.use_device_prng,
-            interpret=cfg.interpret,
         )
         return jax.tree_util.tree_unflatten(treedef, plan.unpack(hat, leaves))
 

@@ -346,15 +346,14 @@ def build_plan(leaves_key: tuple, groups: tuple, mode: str,
 
 def fused_compress(plan: ExchangePlan, flat: Array, tables: tuple,
                    key: Array, *, use_pallas: bool = False,
-                   use_device_prng: bool = False,
-                   interpret: bool = True) -> Array:
+                   use_device_prng: bool = False) -> Array:
     """One fused quantize∘dequantize pass over the planned buffer.
 
     ``tables`` holds one (traced) level table per plan segment, in
     segment order.  Segments that share row geometry — (bucket size,
     norm order, rounding mode) — are processed by ONE kernel invocation
-    with stacked segment-indexed level tables (the SMEM-table mechanism
-    of :mod:`repro.kernels.segment_quantize`); the per-leaf path paid
+    with stacked segment-indexed level tables (held in SMEM by
+    :mod:`repro.kernels.segment_quantize`); the per-leaf path paid
     one quantize + one dequantize launch per leaf.  Returns the f32
     ``hat`` buffer of length ``plan.total`` (padding tails stay zero in
     expectation; live coords are the Definition-1 unbiased estimate).
@@ -396,7 +395,7 @@ def fused_compress(plan: ExchangePlan, flat: Array, tables: tuple,
                 x2d, noise, stacked, seg_rows,
                 num_symbols=num_symbols, q_is_inf=q_is_inf,
                 stochastic=stochastic, use_device_prng=use_device_prng,
-                seed=seed, interpret=interpret,
+                seed=seed,
             )
         else:
             from repro.kernels.common import segment_quant_dequant_rows

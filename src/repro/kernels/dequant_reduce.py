@@ -17,8 +17,7 @@ reduced f32 chunk never leaves VMEM, only the requantized payload
 path).  With on-device PRNG and 4-bit packing that is the paper-grade
 ``K x n/2 + n/2`` wire-and-HBM figure.
 
-Grid tiles rows of buckets (row axis padded to full 8-row tiles); the
-K-reduction is an unrolled loop in the kernel body (K is a static mesh
+Grid tiles rows of buckets (``row_grid``); the K-reduction is an unrolled loop in the kernel body (K is a static mesh
 constant: 2 pods / 3 GAN nodes / 8 DP hosts), so partial sums live in
 VREGs.
 """
@@ -36,10 +35,11 @@ from repro.kernels.common import (
     ROWS_PER_BLOCK,
     dequant_rows,
     pack4_rows,
-    pad_rows,
-    padded_rows,
     prng_uniform,
     quant_rows,
+    row_block,
+    row_grid,
+    tpu_pallas_call,
     unpack4_rows,
 )
 
@@ -64,11 +64,11 @@ def _dequant_reduce_kernel(
     num_workers: int,
     pack4: bool,
 ):
-    out_ref[...] = _mean_rows(idx_ref, norms_ref, levels_ref[...], num_workers, pack4)
+    out_ref[...] = _mean_rows(idx_ref, norms_ref, levels_ref, num_workers, pack4)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_symbols", "num_workers", "bits", "interpret")
+    jax.jit, static_argnames=("num_symbols", "num_workers", "bits")
 )
 def dequant_reduce_blocks(
     idx: jax.Array,    # [K, nb, P] int8
@@ -78,37 +78,32 @@ def dequant_reduce_blocks(
     num_symbols: int,
     num_workers: int,
     bits: int = 8,
-    interpret: bool = True,
 ):
     """Fused DEQ + mean over K workers -> [nb, bucket] f32."""
     del num_symbols
     K, nb, payload_cols = idx.shape
     assert K == num_workers
     bucket = payload_cols if bits == 8 else payload_cols * 2
-    nbp = padded_rows(nb)
-    grid = (nbp // ROWS_PER_BLOCK,)
     kernel = functools.partial(
         _dequant_reduce_kernel, num_workers=num_workers, pack4=bits == 4
     )
-    out = pl.pallas_call(
+    out = tpu_pallas_call(
         kernel,
-        grid=grid,
+        grid=row_grid(nb),
         in_specs=[
             pl.BlockSpec((K, ROWS_PER_BLOCK, payload_cols), lambda i: (0, i, 0)),
-            pl.BlockSpec((K, ROWS_PER_BLOCK), lambda i: (0, i)),
+            row_block(K),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbp, bucket), jnp.float32),
-        interpret=interpret,
-    )(pad_rows(idx, axis=1), pad_rows(norms.astype(jnp.float32), axis=1),
-      levels.astype(jnp.float32))
-    return out[:nb]
+        out_shape=jax.ShapeDtypeStruct((nb, bucket), jnp.float32),
+    )(idx, norms.astype(jnp.float32), levels.astype(jnp.float32))
+    return out
 
 
 def _dequant_reduce_requant_kernel(
     *refs,  # idx [K, BB, P]; norms [K, BB]; noise [BB, bucket] | seed [1];
-            # levels SMEM; out: idx [BB, P] int8, norms [BB] f32
+            # levels SMEM; out: idx [BB, P] int8, norms [1, BB] f32
     num_symbols: int,
     num_workers: int,
     q_is_inf: bool,
@@ -119,18 +114,17 @@ def _dequant_reduce_requant_kernel(
         idx_ref, norms_ref, levels_ref, seed_ref, oidx_ref, onorms_ref = refs
     else:
         idx_ref, norms_ref, noise_ref, levels_ref, oidx_ref, onorms_ref = refs
-    lv = levels_ref[...]
-    reduced = _mean_rows(idx_ref, norms_ref, lv, num_workers, pack4)
+    reduced = _mean_rows(idx_ref, norms_ref, levels_ref, num_workers, pack4)
     r = prng_uniform(seed_ref, reduced.shape) if use_device_prng else noise_ref[...]
-    signed, norms2 = quant_rows(reduced, lv, r, num_symbols, q_is_inf)
-    onorms_ref[...] = norms2
+    signed, norms2 = quant_rows(reduced, levels_ref, r, num_symbols, q_is_inf)
+    onorms_ref[...] = norms2[None, :]
     oidx_ref[...] = pack4_rows(signed) if pack4 else signed.astype(jnp.int8)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "num_symbols", "num_workers", "q_is_inf", "bits", "use_device_prng", "interpret"
+        "num_symbols", "num_workers", "q_is_inf", "bits", "use_device_prng"
     ),
 )
 def dequant_reduce_requantize_blocks(
@@ -145,7 +139,6 @@ def dequant_reduce_requantize_blocks(
     bits: int = 8,
     use_device_prng: bool = False,
     seed=None,
-    interpret: bool = True,
 ):
     """Fused DEQ + mean + re-quantize -> (payload [nb, P] int8, norms [nb]).
 
@@ -157,18 +150,15 @@ def dequant_reduce_requantize_blocks(
     K, nb, payload_cols = idx.shape
     assert K == num_workers
     bucket = payload_cols if bits == 8 else payload_cols * 2
-    nbp = padded_rows(nb)
-    grid = (nbp // ROWS_PER_BLOCK,)
-
-    inputs = [pad_rows(idx, axis=1), pad_rows(norms.astype(jnp.float32), axis=1)]
+    inputs = [idx, norms.astype(jnp.float32)]
     in_specs = [
         pl.BlockSpec((K, ROWS_PER_BLOCK, payload_cols), lambda i: (0, i, 0)),
-        pl.BlockSpec((K, ROWS_PER_BLOCK), lambda i: (0, i)),
+        row_block(K),
     ]
     if not use_device_prng:
         if noise is None:
             raise ValueError("host-noise path needs the uniform noise buffer")
-        inputs.append(pad_rows(noise.astype(jnp.float32)))
+        inputs.append(noise.astype(jnp.float32))
         in_specs.append(pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0)))
     inputs.append(levels.astype(jnp.float32))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -186,21 +176,20 @@ def dequant_reduce_requantize_blocks(
         pack4=bits == 4,
         use_device_prng=use_device_prng,
     )
-    oidx, onorms = pl.pallas_call(
+    oidx, onorms = tpu_pallas_call(
         kernel,
-        grid=grid,
+        grid=row_grid(nb),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((ROWS_PER_BLOCK, payload_cols), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)),
+            row_block(1),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nbp, payload_cols), jnp.int8),
-            jax.ShapeDtypeStruct((nbp,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, payload_cols), jnp.int8),
+            jax.ShapeDtypeStruct((1, nb), jnp.float32),
         ],
-        interpret=interpret,
     )(*inputs)
-    return oidx[:nb], onorms[:nb]
+    return oidx, onorms[0]
 
 
 def dequant_reduce_ref(idx, norms, levels):

@@ -5,10 +5,10 @@ int4 buffer) and per-bucket norms, reconstructs f32 values:
 v = sign(idx) * levels[|idx|] * norm_bucket.  Like the quantizer this is a
 pure bandwidth kernel; the payload is 4x (8x packed) smaller than the
 output, so the kernel is output-bandwidth-bound — tiles are chosen so each
-(8, bucket) f32 output tile is produced from a single contiguous int8
-input tile.  The level lookup is one SMEM-table gather (kernels/common.py)
-instead of the seed's unrolled per-symbol select chain; int4 unpacking
-happens in-kernel so the packed buffer is read directly off the wire.
+(ROWS_PER_BLOCK, bucket) f32 output tile is produced from a single
+contiguous int8 input tile.  The level lookup reads the SMEM level table
+a scalar at a time (kernels/common.py); int4 unpacking happens in-kernel
+so the packed buffer is read directly off the wire.
 """
 
 from __future__ import annotations
@@ -23,15 +23,16 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import (
     ROWS_PER_BLOCK,
     dequant_rows,
-    pad_rows,
-    padded_rows,
+    row_block,
+    row_grid,
+    tpu_pallas_call,
     unpack4_rows,
 )
 
 
 def _dequantize_kernel(
     idx_ref,     # [BB, P] int8 VMEM (P = bucket, or bucket/2 packed)
-    norms_ref,   # [BB] f32 VMEM
+    norms_ref,   # [1, BB] f32 VMEM
     levels_ref,  # [s+2] f32 SMEM
     out_ref,     # [BB, bucket] f32 VMEM
     *,
@@ -39,11 +40,11 @@ def _dequantize_kernel(
 ):
     signed = idx_ref[...]
     signed = unpack4_rows(signed) if pack4 else signed.astype(jnp.int32)
-    out_ref[...] = dequant_rows(signed, levels_ref[...], norms_ref[...])
+    out_ref[...] = dequant_rows(signed, levels_ref, norms_ref[0])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_symbols", "bits", "interpret")
+    jax.jit, static_argnames=("num_symbols", "bits")
 )
 def dequantize_blocks(
     idx2d: jax.Array,
@@ -52,7 +53,6 @@ def dequantize_blocks(
     *,
     num_symbols: int,
     bits: int = 8,
-    interpret: bool = True,
 ):
     """DEQ [nb, P] payload -> [nb, bucket] f32 (P = bucket or bucket/2).
 
@@ -62,19 +62,16 @@ def dequantize_blocks(
     del num_symbols
     nb, payload_cols = idx2d.shape
     bucket = payload_cols if bits == 8 else payload_cols * 2
-    nbp = padded_rows(nb)
-    grid = (nbp // ROWS_PER_BLOCK,)
     kernel = functools.partial(_dequantize_kernel, pack4=bits == 4)
-    out = pl.pallas_call(
+    out = tpu_pallas_call(
         kernel,
-        grid=grid,
+        grid=row_grid(nb),
         in_specs=[
             pl.BlockSpec((ROWS_PER_BLOCK, payload_cols), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)),
+            row_block(1),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbp, bucket), jnp.float32),
-        interpret=interpret,
-    )(pad_rows(idx2d), pad_rows(norms.astype(jnp.float32)), levels.astype(jnp.float32))
-    return out[:nb]
+        out_shape=jax.ShapeDtypeStruct((nb, bucket), jnp.float32),
+    )(idx2d, norms.astype(jnp.float32)[None], levels.astype(jnp.float32))
+    return out

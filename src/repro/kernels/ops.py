@@ -7,9 +7,10 @@ In 4-bit mode the pack/unpack happens *inside* the kernels, so the
 packed buffer — byte-identical to the host-side
 :func:`repro.core.quantization.pack_int4` layout.
 
-On this CPU container the kernels run in TPU interpret mode; on real TPUs
-set ``interpret=False`` (and optionally ``use_device_prng=True`` with a
-seed array, which skips the host noise buffer entirely).
+Mosaic compiles the kernels on a TPU and the Pallas interpreter runs them
+elsewhere (``kernels.common.tpu_pallas_call``); on a TPU,
+``use_device_prng=True`` draws the rounding bits on-core and skips the
+host noise buffer entirely.
 """
 
 from __future__ import annotations
@@ -35,7 +36,6 @@ def quantize_pallas(
     key: jax.Array,
     cfg: QuantConfig,
     *,
-    interpret: bool = True,
     use_device_prng: bool = False,
 ) -> Quantized:
     flat = v.reshape(-1)
@@ -55,7 +55,6 @@ def quantize_pallas(
         bits=cfg.bits,
         use_device_prng=use_device_prng,
         seed=seed,
-        interpret=interpret,
     )
     return Quantized(payload=idx.reshape(-1), norms=norms, n=n)
 
@@ -64,8 +63,6 @@ def dequantize_pallas(
     qt: Quantized,
     levels: jax.Array,
     cfg: QuantConfig,
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     payload_cols = cfg.bucket_size if cfg.bits == 8 else cfg.bucket_size // 2
     idx2d = qt.payload.reshape(-1, payload_cols)
@@ -75,6 +72,5 @@ def dequantize_pallas(
         levels,
         num_symbols=cfg.num_symbols,
         bits=cfg.bits,
-        interpret=interpret,
     )
     return out.reshape(-1)[: qt.n]

@@ -8,11 +8,10 @@ so the design goals are (a) stream HBM->VMEM in (8,128)-aligned tiles,
 rounding, int8 emission AND int4 packing fused, (c) per-bucket norms
 computed on-chip so the f32 input is read exactly once.
 
-Layout: the wrapper reshapes the flat vector to [nb, bucket] and pads the
-row axis to a multiple of ROWS_PER_BLOCK, so every grid step works on a
-full (8, bucket) tile (the seed's gcd tiling degenerated to 1-row blocks
-for odd nb).  The level table (s+2 <= 128 scalars) sits in SMEM; bracket
-endpoints come from SMEM-table gathers (see kernels/common.py).
+Layout: the wrapper reshapes the flat vector to [nb, bucket] and every
+grid step works on one (ROWS_PER_BLOCK, bucket) tile (the last one may be
+partial); the per-row norms leave as one lane-dense [1, rows] row.  The level table (s+2 <= 128 scalars) sits in
+SMEM and is read a scalar at a time (see kernels/common.py).
 
 In 4-bit mode the payload is packed two-per-byte *inside* the kernel —
 the [nb, bucket/2] int8 buffer this kernel writes is exactly what the
@@ -21,10 +20,11 @@ collective moves, halving wire bytes versus shipping unpacked indices.
 Randomness: production TPUs use the on-core PRNG (``use_device_prng=True``
 — ``pltpu.prng_seed`` / ``prng_random_bits`` seeded from a traced int32
 scalar), which skips generating and re-reading a full-size f32 noise
-buffer every exchange.  Interpret mode on CPU cannot lower those
-primitives, so the *validated* path streams uniform noise generated with
-``jax.random`` (bit-compatible with the jnp reference oracle) — selected
-by ``use_device_prng=False`` (default).  See DESIGN.md §Hardware adaptation.
+buffer every exchange.  The Pallas interpreter (any platform but a TPU)
+cannot lower those primitives, so the path it validates streams uniform
+noise generated with ``jax.random`` (bit-compatible with the jnp
+reference oracle) — selected by ``use_device_prng=False`` (default).
+See DESIGN.md §Hardware adaptation.
 """
 
 from __future__ import annotations
@@ -39,16 +39,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import (
     ROWS_PER_BLOCK,
     pack4_rows,
-    pad_rows,
-    padded_rows,
     prng_uniform,
     quant_rows,
+    row_block,
+    row_grid,
+    tpu_pallas_call,
 )
 
 
 def _quantize_kernel(
     *refs,  # x [BB, bucket] f32; noise [BB, bucket] f32 | seed [1] i32 SMEM;
-            # levels [s+2] f32 SMEM; out: idx [BB, P] int8, norms [BB] f32
+            # levels [s+2] f32 SMEM; out: idx [BB, P] int8, norms [1, BB] f32
     num_symbols: int,
     q_is_inf: bool,
     pack4: bool,
@@ -59,16 +60,15 @@ def _quantize_kernel(
     else:
         x_ref, noise_ref, levels_ref, idx_ref, norms_ref = refs
     x = x_ref[...]
-    lv = levels_ref[...]
     r = prng_uniform(seed_ref, x.shape) if use_device_prng else noise_ref[...]
-    signed, norms = quant_rows(x, lv, r, num_symbols, q_is_inf)
-    norms_ref[...] = norms
+    signed, norms = quant_rows(x, levels_ref, r, num_symbols, q_is_inf)
+    norms_ref[...] = norms[None, :]
     idx_ref[...] = pack4_rows(signed) if pack4 else signed.astype(jnp.int8)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_symbols", "q_is_inf", "bits", "use_device_prng", "interpret"),
+    static_argnames=("num_symbols", "q_is_inf", "bits", "use_device_prng"),
 )
 def quantize_blocks(
     x2d: jax.Array,
@@ -80,7 +80,6 @@ def quantize_blocks(
     bits: int = 8,
     use_device_prng: bool = False,
     seed=None,
-    interpret: bool = True,
 ):
     """Quantize [nb, bucket] f32 -> (payload int8, f32 norms).
 
@@ -98,15 +97,12 @@ def quantize_blocks(
     if bits == 4 and bucket % 2:
         raise ValueError("4-bit packing needs an even bucket size")
     payload_cols = bucket if bits == 8 else bucket // 2
-    nbp = padded_rows(nb)
-    grid = (nbp // ROWS_PER_BLOCK,)
-
-    inputs = [pad_rows(x2d.astype(jnp.float32))]
+    inputs = [x2d.astype(jnp.float32)]
     in_specs = [pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0))]
     if not use_device_prng:
         if noise is None:
             raise ValueError("host-noise path needs the uniform noise buffer")
-        inputs.append(pad_rows(noise.astype(jnp.float32)))
+        inputs.append(noise.astype(jnp.float32))
         in_specs.append(pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0)))
     inputs.append(levels.astype(jnp.float32))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -123,18 +119,17 @@ def quantize_blocks(
         pack4=bits == 4,
         use_device_prng=use_device_prng,
     )
-    idx, norms = pl.pallas_call(
+    idx, norms = tpu_pallas_call(
         kernel,
-        grid=grid,
+        grid=row_grid(nb),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((ROWS_PER_BLOCK, payload_cols), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)),
+            row_block(1),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nbp, payload_cols), jnp.int8),
-            jax.ShapeDtypeStruct((nbp,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, payload_cols), jnp.int8),
+            jax.ShapeDtypeStruct((1, nb), jnp.float32),
         ],
-        interpret=interpret,
     )(*inputs)
-    return idx[:nb], norms[:nb]
+    return idx, norms[0]

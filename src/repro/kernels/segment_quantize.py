@@ -6,17 +6,17 @@ tail.  With an :class:`~repro.core.exchange_plan.ExchangePlan` the whole
 pytree lives in one flat buffer whose bucket rows are mapped to level
 tables by a static segment table — this kernel consumes that layout in a
 single invocation: the stacked ``[T, S_max]`` level-table buffer sits in
-SMEM (the same SMEM-table mechanism every exchange kernel uses, indexed
-per row by the segment id), the bracket search is one masked
-compare-accumulate over the union of interior levels, and the payload
+SMEM (read a scalar at a time like every exchange kernel's table, and
+selected per row by the segment id), the bracket search is one masked
+compare pass over the union of interior levels, and the payload
 indices never leave registers — only the dequantized f32 estimate is
 written, so HBM traffic is read-4n + write-4n regardless of how many
 per-layer policies the plan carries.
 
 Like every exchange kernel: host-noise mode (``use_device_prng=False``,
-bit-compatible with the jnp reference — the validated path on this CPU
-container) or the on-core PRNG (TPU only, seeded per grid step from a
-traced int32 scalar).
+bit-compatible with the jnp reference, and the only mode the Pallas
+interpreter runs) or the on-core PRNG (TPU only, seeded per grid step
+from a traced int32 scalar).
 """
 
 from __future__ import annotations
@@ -30,16 +30,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
     ROWS_PER_BLOCK,
-    pad_rows,
-    padded_rows,
     prng_uniform,
+    row_block,
+    row_grid,
     segment_quant_dequant_rows,
+    tpu_pallas_call,
 )
 
 
 def _seg_qdq_kernel(
     *refs,  # x [BB, bucket] f32; noise [BB, bucket] f32 | seed [1] i32 SMEM;
-            # seg [BB] i32; tables [T, S_max] f32 SMEM; out [BB, bucket] f32
+            # seg [1, BB] i32; tables [T, S_max] f32 SMEM; out [BB, bucket] f32
     num_symbols: tuple,
     q_is_inf: bool,
     stochastic: bool,
@@ -52,7 +53,7 @@ def _seg_qdq_kernel(
         x_ref, noise_ref, seg_ref, tables_ref, out_ref = refs
         r = noise_ref[...]
     out_ref[...] = segment_quant_dequant_rows(
-        x_ref[...], tables_ref[...], seg_ref[...], r,
+        x_ref[...], tables_ref, seg_ref[0], r,
         num_symbols=num_symbols, q_is_inf=q_is_inf, stochastic=stochastic,
     )
 
@@ -61,7 +62,6 @@ def _seg_qdq_kernel(
     jax.jit,
     static_argnames=(
         "num_symbols", "q_is_inf", "stochastic", "use_device_prng",
-        "interpret",
     ),
 )
 def quantize_dequantize_segments(
@@ -75,7 +75,6 @@ def quantize_dequantize_segments(
     stochastic: bool = True,
     use_device_prng: bool = False,
     seed=None,
-    interpret: bool = True,
 ):
     """Fused Q∘DEQ of [nb, bucket] f32 under per-row level tables.
 
@@ -90,18 +89,15 @@ def quantize_dequantize_segments(
     nb, bucket = x2d.shape
     if seg_ids.shape != (nb,):
         raise ValueError(f"seg_ids must be [nb]={nb}, got {seg_ids.shape}")
-    nbp = padded_rows(nb)
-    grid = (nbp // ROWS_PER_BLOCK,)
-
-    inputs = [pad_rows(x2d.astype(jnp.float32))]
+    inputs = [x2d.astype(jnp.float32)]
     in_specs = [pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0))]
     if not use_device_prng:
         if noise is None:
             raise ValueError("host-noise path needs the uniform noise buffer")
-        inputs.append(pad_rows(noise.astype(jnp.float32)))
+        inputs.append(noise.astype(jnp.float32))
         in_specs.append(pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0)))
-    inputs.append(pad_rows(seg_ids.astype(jnp.int32)))
-    in_specs.append(pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)))
+    inputs.append(seg_ids.astype(jnp.int32)[None])
+    in_specs.append(row_block(1))
     inputs.append(tables.astype(jnp.float32))
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if use_device_prng:
@@ -117,12 +113,11 @@ def quantize_dequantize_segments(
         stochastic=stochastic,
         use_device_prng=use_device_prng,
     )
-    out = pl.pallas_call(
+    out = tpu_pallas_call(
         kernel,
-        grid=grid,
+        grid=row_grid(nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((ROWS_PER_BLOCK, bucket), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbp, bucket), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((nb, bucket), jnp.float32),
     )(*inputs)
-    return out[:nb]
+    return out

@@ -3,13 +3,12 @@
 Two small, launcher-shared concerns live here so train / dryrun / serve
 stay flag-thin:
 
-* :func:`enable_compilation_cache` — point jax's persistent compilation
-  cache (``jax.experimental.compilation_cache``) at an on-disk directory
-  so a fresh process re-loads compiled executables instead of repaying
-  the cold compile (the full tinyllama train step compiles for ~293 s in
-  this container; a warm cache turns that into a disk read).  This is
-  the prep work for the multi-host ROADMAP item, where EVERY process of
-  the fleet pays the cold compile without it.
+* :func:`enable_compilation_cache` — keep jax's persistent compilation
+  cache in one directory, so a fresh process re-loads compiled
+  executables instead of repaying the cold compile.  The directory is
+  part of every entry's key, so it never moves: ``JAX_COMPILATION_CACHE_DIR``
+  where the environment sets it, else ``.jax_cache/`` at the checkout's
+  root.
 
 * :func:`profile_trace` — a context manager around
   ``jax.profiler.start_trace`` / ``stop_trace`` emitting a TensorBoard-
@@ -30,42 +29,44 @@ import contextlib
 import os
 import sys
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: the in-checkout cache directory used when ``ENV_VAR`` is unset
+#: (git-ignored): <repo>/.jax_cache, fixed so its entries are found again
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Enable jax's persistent on-disk compilation cache at ``cache_dir``.
 
-    Returns True when the cache was wired up, False when ``cache_dir`` is
-    empty (feature off) or enabling failed (warning printed, run
-    continues uncached).  Must be called BEFORE the first jit compile to
-    be of any use; the launchers call it right after arg parsing.
+def cache_dir() -> str:
+    """The persistent cache's directory: ``$JAX_COMPILATION_CACHE_DIR``
+    when set (and non-empty), else :data:`DEFAULT_DIR`."""
+    return os.environ.get(ENV_VAR) or DEFAULT_DIR
 
-    The min-compile-time / min-entry-size thresholds are dropped to zero
-    so even the reduced smoke-size steps are cached — the point in CI and
-    tests is determinism of the warm path, not saving only the 293 s
-    whales.
+
+def enable_compilation_cache() -> str:
+    """Turn jax's persistent on-disk compilation cache on; return its
+    directory ("" when enabling failed: warning printed, run continues
+    uncached).  Must be called BEFORE the first jit compile to be of any
+    use; the launchers call it right after arg parsing.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, jax reads the variable itself
+    and nothing is set here; otherwise the cache goes to
+    :data:`DEFAULT_DIR`.
     """
-    if not cache_dir:
-        return False
+    path = cache_dir()
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
-        from jax.experimental.compilation_cache import compilation_cache
+        os.makedirs(path, exist_ok=True)
+        if not os.environ.get(ENV_VAR):
+            from jax.experimental.compilation_cache import compilation_cache
 
-        compilation_cache.set_cache_dir(cache_dir)
-        # cache everything, however small/fast the compile was
-        for flag, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
-            try:
-                jax.config.update(flag, val)
-            except AttributeError:
-                pass  # older jax: threshold flag absent, cache still on
-        return True
-    except (OSError, ImportError) as e:
+            compilation_cache.set_cache_dir(path)
+        return path
+    except OSError as e:
         print(f"[cache] WARNING: compilation cache disabled ({e})",
               file=sys.stderr, flush=True)
-        return False
+        return ""
 
 
 @contextlib.contextmanager

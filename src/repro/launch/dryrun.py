@@ -429,17 +429,13 @@ def main():
                          "is leafwise, where bucketing is rejected loudly)")
     ap.add_argument("--overlap", default="off",
                     choices=("off", "bucketed", "defer_tail"))
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="persistent on-disk XLA compilation cache — the "
-                         "512-device combo compiles are exactly the cold "
-                         "starts this amortizes across dryrun invocations")
     args = ap.parse_args()
 
     from repro.launch.cache import enable_compilation_cache
 
-    if enable_compilation_cache(args.compilation_cache_dir):
-        print(f"[dryrun] compilation cache: {args.compilation_cache_dir}",
-              flush=True)
+    cache = enable_compilation_cache()
+    if cache:
+        print(f"[dryrun] compilation cache: {cache}", flush=True)
 
     archs = sorted(ARCHS) if (args.all or not args.arch) else [args.arch]
     shapes = (

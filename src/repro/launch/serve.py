@@ -32,41 +32,27 @@ Examples (CPU, reduced model):
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import sys
 import time
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-def _early_flags():
-    # must run before jax import
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--host-devices", type=int, default=0)
-    args, _ = ap.parse_known_args()
-    if args.host_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}"
-        )
-
-
-_early_flags()
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.checkpoint import checkpointing  # noqa: E402
-from repro.configs.registry import ARCHS, get_config  # noqa: E402
-from repro.core import faults  # noqa: E402
-from repro.core.exchange import ExchangeConfig  # noqa: E402
-from repro.core.quantization import QuantConfig  # noqa: E402
-from repro.core.retry import BackoffPolicy  # noqa: E402
-from repro.launch.mesh import make_host_mesh  # noqa: E402
-from repro.launch.steps import make_serve_step  # noqa: E402
-from repro.models import transformer  # noqa: E402
-from repro.models.model import build  # noqa: E402
-from repro.serve.engine import ServeEngine  # noqa: E402
-from repro.serve.scheduler import Request  # noqa: E402
+from repro.checkpoint import checkpointing
+from repro.configs.registry import ARCHS, get_config
+from repro.core import faults
+from repro.core.exchange import ExchangeConfig
+from repro.core.quantization import QuantConfig
+from repro.core.retry import BackoffPolicy
+from repro.launch.cache import enable_compilation_cache
+from repro.launch.mesh import make_host_mesh
+from repro.launch.steps import make_serve_step
+from repro.models import transformer
+from repro.models.model import build
+from repro.serve.engine import ServeEngine
+from repro.serve.scheduler import Request
 
 
 def prefill_into_cache(model, params, tokens, cache):
@@ -376,7 +362,7 @@ def main(argv=None):
     ap.add_argument("--arch", choices=sorted(ARCHS), default="gemma-2b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="force N host devices (handled before jax import)")
+                    help="force N host (CPU) devices")
     ap.add_argument("--batch", type=int, default=4,
                     help="packed decode slots (dense fallback: batch size)")
     ap.add_argument("--requests", default="0",
@@ -430,21 +416,21 @@ def main(argv=None):
                          "last intact snapshot before giving up")
     faults.add_fault_spec_flag(ap, scope="serve")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="persistent on-disk XLA compilation cache; warm "
-                         "serving restarts skip the prefill/decode compiles")
     args = ap.parse_args(argv)
+    if args.host_devices:
+        # read when jax first initialises its backends, which no code
+        # before this line does
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.host_devices}"
+        )
 
-    from repro.launch.cache import enable_compilation_cache
-
-    if enable_compilation_cache(args.compilation_cache_dir):
-        print(f"[serve] compilation cache: {args.compilation_cache_dir}",
-              flush=True)
+    cache = enable_compilation_cache()
+    if cache:
+        print(f"[serve] compilation cache: {cache}", flush=True)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = dataclasses.replace(cfg, dtype="float32")
     model = build(cfg)
     key = jax.random.PRNGKey(args.seed)
     params = _restore_params(model, cfg, args, key)

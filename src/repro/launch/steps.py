@@ -7,7 +7,7 @@ Distribution model (see DESIGN.md §5):
   reduction (fast ICI — compression not worth it there; App. I trade-off).
 * Across pods: params are replicated, the gradient reduction crosses the
   slow inter-pod links — this is where Algorithm 1's quantized exchange is
-  applied, via ``shard_map`` over the ``pod`` axis with ``auto`` GSPMD for
+  applied, via ``shard_map`` over the ``pod`` axis with automatic GSPMD for
   the inner axes.  ``axis_name="data"`` gives the paper's original
   DDP-over-Ethernet setting (params replicated over data; used by the CPU
   examples with 8 host devices).
@@ -73,7 +73,7 @@ from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import faults as faults_mod
@@ -95,7 +95,12 @@ Array = jax.Array
 
 def cross_entropy_loss(logits: Array, labels: Array, aux: Array) -> Array:
     ll = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(ll, labels[..., None], axis=-1)[..., 0]
+    # the label's log-probability as a one-hot contraction (exact: one
+    # term, the rest zeros), not a gather: XLA's SPMD partitioner aborts
+    # on batched gathers inside a partially-manual shard_map (the
+    # multi-pod exchange)
+    pick = jax.nn.one_hot(labels, ll.shape[-1], dtype=ll.dtype)
+    nll = -jnp.sum(ll * pick, axis=-1)
     return jnp.mean(nll) + 0.01 * aux
 
 
@@ -505,8 +510,8 @@ def make_train_step(
     # params/opt_state/ex_state replicated over the compressed axis (pure
     # DP across it); batch sharded on its leading dim; key replicated
     # (folded inside); all OTHER mesh axes stay under automatic (GSPMD)
-    # partitioning — shard_map's ``auto`` frozenset selects the non-manual
-    # subset.  The sharded arange gives every device its position along
+    # partitioning — shard_map's ``axis_names`` makes only the exchange
+    # axis manual.  The sharded arange gives every device its position along
     # the exchange axis WITHOUT lax.axis_index (whose partition-id
     # lowering the SPMD partitioner rejects on partially-manual meshes);
     # the folded value is identical, so so are all downstream bytes.
@@ -531,8 +536,8 @@ def make_train_step(
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(P(), P(), P(), metric_specs),
-            check_rep=False,
-            auto=frozenset(mesh.axis_names) - {axis_name},
+            check_vma=False,
+            axis_names={axis_name},
         )
         return fn(*args)
 

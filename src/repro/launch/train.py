@@ -19,55 +19,36 @@ Example (CPU, reduced model, compressed 8-way DP exchange):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import sys
 import time
+from typing import Any, Callable, NamedTuple, Optional
 
+import jax
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-def _early_flags():
-    # must run before jax import
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--host-devices", type=int, default=0)
-    args, _ = ap.parse_known_args()
-    if args.host_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}"
-        )
-
-
-_early_flags()
-
-import contextlib  # noqa: E402
-import dataclasses  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.checkpoint import checkpointing  # noqa: E402
-from repro.configs.base import INPUT_SHAPES, ShapeConfig  # noqa: E402
-from repro.configs.registry import ARCHS, get_config  # noqa: E402
-from repro.core import faults  # noqa: E402
-from repro.core.exchange import (  # noqa: E402
+from repro.checkpoint import checkpointing
+from repro.configs.base import ShapeConfig
+from repro.configs.registry import ARCHS, get_config
+from repro.core import faults
+from repro.core.exchange import (
     ExchangeConfig,
     make_exchange,
     null_exchange_state,
     registered_compressors,
 )
-from repro.core.quantization import QuantConfig  # noqa: E402
-from repro.data.pipeline import add_modality_stubs, make_pipeline  # noqa: E402
-from repro.launch.cache import (  # noqa: E402
-    enable_compilation_cache,
-    profile_trace,
-)
-from repro.launch.steps import make_train_step  # noqa: E402
-from repro.models.model import build, param_pspecs  # noqa: E402
-from repro.optim import optimizers as opt  # noqa: E402
-from repro.optim import qgenx as qgenx_opt  # noqa: E402
+from repro.core.quantization import QuantConfig
+from repro.data.pipeline import add_modality_stubs, make_pipeline
+from repro.launch.cache import enable_compilation_cache, profile_trace
+from repro.launch.steps import make_train_step
+from repro.models.model import build
+from repro.optim import optimizers as opt
+from repro.optim import qgenx as qgenx_opt
 
 
-def build_exchange_config(args, n_dev: int):
+def build_exchange_config(args):
     """Translate CLI flags into one ExchangeConfig (or None = no exchange).
 
     This is the only place the launcher decides between the compressed
@@ -80,9 +61,9 @@ def build_exchange_config(args, n_dev: int):
         quant = QuantConfig(num_levels=15 if bits == 8 else 5, bits=bits,
                             bucket_size=512)
     # exchange is active when there is something to compress (or an
-    # explicitly requested non-default compressor) and >1 device to cross
-    active = n_dev > 1 and (quant is not None or args.compressor != "qgenx")
-    if not active:
+    # explicitly requested non-default compressor) — on one device too:
+    # the quantized exchange then runs over the 1-device mesh
+    if quant is None and args.compressor == "qgenx":
         return None
     return ExchangeConfig(
         compressor=args.compressor,
@@ -90,7 +71,6 @@ def build_exchange_config(args, n_dev: int):
         mode=args.compress_mode,
         axis_name=args.compress_axis,
         use_pallas=args.use_pallas,
-        interpret=True,  # CPU container; real TPU launchers flip this off
         level_schedule=args.level_schedule,
         level_update_every=args.level_update_every,
         rand_frac=args.rand_frac,
@@ -103,7 +83,55 @@ def build_exchange_config(args, n_dev: int):
     )
 
 
-def main(argv=None):
+class TrainRun(NamedTuple):
+    """What one training run is built from (see :func:`build_run`)."""
+
+    model: Any
+    opt_cfg: opt.OptimizerConfig
+    ex_cfg: Optional[ExchangeConfig]
+    ex: Any  # Exchange | None
+    step: Callable  # the jitted step; donates params, opt_state, ex_state
+    state_sharding: NamedSharding  # where the step keeps its state
+    n_dev: int
+
+    def init_state(self, key):
+        """Fresh (params, opt_state, ex_state); ``jax.eval_shape`` of it
+        gives the shapes without allocating them."""
+        params = self.model.init(key)
+        opt_state = opt.init_state(self.opt_cfg, params)
+        # template + axis size let contractive compressors size their
+        # per-worker error memory; unbiased compressors ignore both
+        ex_state = (self.ex.init_state(template=params,
+                                       num_workers=self.n_dev)
+                    if self.ex is not None else null_exchange_state())
+        return params, opt_state, ex_state
+
+
+def build_run(args, cfg, mesh, fault_spec) -> TrainRun:
+    """Model, optimizer, exchange and jitted step for the parsed ``args``
+    on ``mesh`` (its ``data`` axis is the exchange's)."""
+    n_dev = mesh.size
+    opt_cfg = opt.OptimizerConfig(name=args.optimizer, lr=args.lr,
+                                  gamma_scale=args.gamma_scale,
+                                  method=args.method)
+    ex_cfg = build_exchange_config(args)
+    ex = make_exchange(ex_cfg) if ex_cfg is not None else None
+    model = build(cfg)
+    step_fn = make_train_step(
+        model, opt_cfg, exchange=ex, mesh=mesh, guard=args.guard,
+        fault_spec=fault_spec if fault_spec.events else None,
+    )
+    # donate ALL carried state — params, opt_state AND ex_state — so XLA
+    # reuses the buffers (incl. the plan's flat exchange scratch) across
+    # steps instead of allocating fresh ones; the step returns each tree
+    # with identical structure, and checkpointing copies host-side before
+    # the next call invalidates the donated inputs
+    step = jax.jit(step_fn, donate_argnums=(0, 1, 2))
+    return TrainRun(model, opt_cfg, ex_cfg, ex, step,
+                    NamedSharding(mesh, P()), n_dev)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true", help="smoke-size model")
@@ -148,10 +176,6 @@ def main(argv=None):
                          "carried in ExchangeState.pending and applied one "
                          "sync late, overlapping step N's tail exchange "
                          "with step N+1's forward (DESIGN.md §10)")
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="persistent on-disk XLA compilation cache: a fresh "
-                         "process re-loads compiled steps instead of "
-                         "repaying the cold compile (multi-host prep)")
     ap.add_argument("--profile-dir", default="",
                     help="emit a jax.profiler trace of the train loop here "
                          "(named_scope-annotated per exchange bucket; view "
@@ -189,40 +213,50 @@ def main(argv=None):
                     help="on restore, reset INCOMPATIBLE auxiliary state "
                          "(ex_state) to fresh init instead of exiting; "
                          "params/opt_state mismatches always exit")
-    ap.add_argument("--host-devices", type=int, default=0)
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="force N host (CPU) devices")
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--repeat-batch", action="store_true",
                     help="train on one repeated batch (fast-convergence tests)")
-    args = ap.parse_args(argv)
+    return ap
 
-    if enable_compilation_cache(args.compilation_cache_dir):
-        print(f"[train] compilation cache: {args.compilation_cache_dir}",
-              flush=True)
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    if args.host_devices:
+        # read when jax first initialises its backends, which no code
+        # before this line does
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.host_devices}"
+        )
+
+    cache = enable_compilation_cache()
+    if cache:
+        print(f"[train] compilation cache: {cache}", flush=True)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = dataclasses.replace(cfg, dtype="float32")  # CPU-friendly
 
     n_dev = jax.device_count()
     mesh = Mesh(np.array(jax.devices()).reshape(n_dev), ("data",))
-    model = build(cfg)
+    fault_spec = faults.parse_fault_spec_arg(args.fault_spec, scope="train")
+    if fault_spec.events:
+        print(f"[train] fault schedule: {args.fault_spec}", flush=True)
+        if fault_spec.has_device_events and not args.guard:
+            print("[train] WARNING: device faults scheduled without --guard "
+                  "— non-finite steps will NOT be rejected", flush=True)
+    run = build_run(args, cfg, mesh, fault_spec)
+    model, ex_cfg, ex = run.model, run.ex_cfg, run.ex
     key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
-    opt_cfg = opt.OptimizerConfig(name=args.optimizer, lr=args.lr,
-                                  gamma_scale=args.gamma_scale,
-                                  method=args.method)
-    opt_state = opt.init_state(opt_cfg, params)
-
-    ex_cfg = build_exchange_config(args, n_dev)
-    ex = make_exchange(ex_cfg) if ex_cfg is not None else None
-    # template + axis size let contractive compressors size their
-    # per-worker error memory; unbiased compressors ignore both
-    ex_state = (ex.init_state(template=params, num_workers=n_dev)
-                if ex is not None else null_exchange_state())
+    # made where the step keeps its state (replicated on the mesh): state
+    # placed otherwise has another type, and the step would be traced and
+    # compiled a second time
+    params, opt_state, ex_state = jax.jit(
+        run.init_state, out_shardings=run.state_sharding)(key)
     if ex is not None:
         print(f"[train] exchange: compressor={ex_cfg.compressor} "
               f"mode={ex_cfg.mode} axis={ex_cfg.axis_name} "
@@ -234,29 +268,9 @@ def main(argv=None):
               flush=True)
     if args.optimizer == "qgenx":
         print(f"[train] qgenx method={args.method}", flush=True)
-
-    fault_spec = faults.parse_fault_spec_arg(args.fault_spec, scope="train")
-    if fault_spec.events:
-        print(f"[train] fault schedule: {args.fault_spec}", flush=True)
-        if fault_spec.has_device_events and not args.guard:
-            print("[train] WARNING: device faults scheduled without --guard "
-                  "— non-finite steps will NOT be rejected", flush=True)
-    step_fn = make_train_step(
-        model, opt_cfg, exchange=ex, mesh=mesh, guard=args.guard,
-        fault_spec=fault_spec if fault_spec.events else None,
-    )
     needs_fault_step = fault_spec.has_device_events
     watchdog = faults.Watchdog(args.rollback_after) if args.guard else None
-    repl = NamedSharding(mesh, P())
-    dp = NamedSharding(mesh, P("data"))
-    batch_sharding = {"tokens": NamedSharding(mesh, P("data", None)),
-                      "labels": NamedSharding(mesh, P("data", None))}
-    # donate ALL carried state — params, opt_state AND ex_state — so XLA
-    # reuses the buffers (incl. the plan's flat exchange scratch) across
-    # steps instead of allocating fresh ones; the step returns each tree
-    # with identical structure, and checkpointing copies host-side before
-    # the next call invalidates the donated inputs
-    jitted = jax.jit(step_fn, donate_argnums=(0, 1, 2))
+    jitted = run.step
 
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     pipe = make_pipeline(cfg, shape, seed=args.seed)
@@ -293,20 +307,18 @@ def main(argv=None):
             print(f"[train] no intact checkpoint at "
                   f"{args.checkpoint_dir}: {e}", file=sys.stderr)
             raise SystemExit(2)
-        params = trees.get("params", params)
-        opt_state = trees.get("opt_state", opt_state)
-        ex_state = trees.get("ex_state", ex_state)
+        params, opt_state, ex_state = jax.device_put(
+            (trees.get("params", params), trees.get("opt_state", opt_state),
+             trees.get("ex_state", ex_state)), run.state_sharding)
         for name in reset:
             print(f"[train] checkpoint {name} incompatible with this run's "
                   f"config; reset to fresh init (--allow-ckpt-reset)")
         pipe.restore({"step": start_step, "seed": args.seed})
         print(f"[train] restored step {start_step}")
 
-    # ambient mesh for sharding propagation (jax 0.4.x: Mesh is the
-    # context manager; jax.sharding.set_mesh arrived in later releases)
-    mesh_ctx = mesh if n_dev > 1 else None
-    if mesh_ctx is not None:
-        mesh_ctx.__enter__()
+    # ambient mesh for sharding propagation
+    if n_dev > 1:
+        mesh.__enter__()
     times = []
     fixed_batch = add_modality_stubs(next(pipe), cfg, seed=args.seed)
     # --profile-dir: one jax.profiler trace spanning the whole loop (the
@@ -344,9 +356,9 @@ def main(argv=None):
                     print(f"[train] watchdog: optimizer stats at rollback "
                           f"{qgenx_opt.state_norms(opt_state)}", flush=True)
                 snap_step, trees = watchdog.rollback()
-                params = trees["params"]
-                opt_state = trees["opt_state"]
-                ex_state = trees["ex_state"]
+                params, opt_state, ex_state = jax.device_put(
+                    (trees["params"], trees["opt_state"], trees["ex_state"]),
+                    run.state_sharding)
                 print(f"[train] watchdog: rolled back to the step-"
                       f"{snap_step} snapshot ({watchdog.summary()})",
                       flush=True)

@@ -78,8 +78,8 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpointing
 from repro.configs.base import ModelConfig
@@ -197,7 +197,7 @@ class ServeEngine:
         else:
             self.axis = self.ex.cfg.axis_name
             self.K = mesh.shape[self.axis]
-            self.ex_state = self.ex.init_state()
+            self.ex_state = self._fresh_ex_state()
             self.cache = _tree_stack_lead(KVC.init_paged_cache(self.pc), self.K)
             self._decode = jax.jit(self._make_dist_decode(), donate_argnums=(0,))
             # analytic operand bytes of the per-step logit exchange — the
@@ -208,6 +208,13 @@ class ServeEngine:
             self.wire_per_step = float(
                 self.ex.wire_bytes_tree(logits_like, self.K)
             )
+
+    def _fresh_ex_state(self):
+        # placed as the decode step returns it (replicated on the mesh): a
+        # host-built state has another type, and the first wave after it
+        # would trace and compile the decode step a second time
+        return jax.device_put(self.ex.init_state(),
+                              NamedSharding(self.mesh, P()))
 
     # -- jitted entry points -----------------------------------------------
 
@@ -286,7 +293,7 @@ class ServeEngine:
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
             return fn(*args)
 
@@ -326,7 +333,7 @@ class ServeEngine:
                         core, mesh=mesh,
                         in_specs=(P(axis), P(), P(), P(), P(), P(axis)),
                         out_specs=(P(), P(axis)),
-                        check_rep=False,
+                        check_vma=False,
                     )
                     return sm(caches, params, tokens, pages, keys, axis_ix)
 
@@ -797,7 +804,7 @@ class ServeEngine:
         self._stalled_rids = set()
         self._committed = {}
         if self.ex is not None:
-            self.ex_state = self.ex.init_state()
+            self.ex_state = self._fresh_ex_state()
 
     @property
     def cache_bytes(self) -> int:

@@ -21,7 +21,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 from repro.core.exchange import ExchangeConfig, make_exchange  # noqa: E402
 from repro.core.quantization import QuantConfig  # noqa: E402
@@ -58,7 +58,7 @@ def run(x, key, mode):
         mesh=mesh,
         in_specs=(P("data", None), P()),
         out_specs=P("data", None),
-        check_rep=False,
+        check_vma=False,
     )(x, key)
 
 
@@ -94,7 +94,7 @@ def ftree(t, k):
 tree_specs = {"w": P("data", None, None), "b": P("data", None)}
 run_tree = jax.jit(
     shard_map(ftree, mesh=mesh, in_specs=(tree_specs, P()), out_specs=tree_specs,
-              check_rep=False)
+              check_vma=False)
 )
 acc_w, acc_b = 0, 0
 for t in range(TRIALS):
@@ -118,7 +118,7 @@ def fexact(t, k):
 
 out = jax.jit(
     shard_map(fexact, mesh=mesh, in_specs=(tree_specs, P()), out_specs=tree_specs,
-              check_rep=False)
+              check_vma=False)
 )(tree, jax.random.PRNGKey(0))
 np.testing.assert_allclose(np.asarray(out["w"])[0], true["w"], rtol=1e-5)
 print("PASS fp32 fallback exact", flush=True)

@@ -34,7 +34,7 @@ import tempfile  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from repro.checkpoint.checkpointing import restore, save  # noqa: E402
@@ -173,7 +173,7 @@ for bits in (8, 4):
                 shard_map(f, mesh=mesh,
                           in_specs=({"w": P(), "b": P()}, P()),
                           out_specs=({"w": P(), "b": P()},) * 2,
-                          check_rep=False)
+                          check_vma=False)
             )(grid_tree, KEY)
         for kk in grid_tree:
             np.testing.assert_array_equal(

@@ -31,7 +31,7 @@ import functools  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from repro.configs.registry import get_config  # noqa: E402
@@ -125,7 +125,7 @@ def run_pmean(ex1, tree, with_mask):
         return jax.jit(
             shard_map(f, mesh=mesh,
                       in_specs=({k: P("data") for k in tree}, P()),
-                      out_specs=(specs, P()), check_rep=False)
+                      out_specs=(specs, P()), check_vma=False)
         )(tree, jax.random.PRNGKey(7))
 
 
@@ -166,7 +166,7 @@ x = jax.random.normal(jax.random.PRNGKey(5), (K, 257), jnp.float32)
 with mesh:
     got = jax.jit(
         shard_map(f_masked, mesh=mesh, in_specs=(P("data"), P("data")),
-                  out_specs=P("data"), check_rep=False)
+                  out_specs=P("data"), check_vma=False)
     )(x, jnp.arange(K, dtype=jnp.int32))
 alive_mean = np.asarray(x)[[i for i in range(K) if i != DEAD]].mean(axis=0)
 for i in range(K):  # every worker (incl. the dead one) holds the alive mean

@@ -26,7 +26,7 @@ import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 import repro.core.exchange as exchange_mod  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
@@ -63,6 +63,9 @@ def run(recenter_every, steps):
     params = params0
     opt_state = opt.init_state(opt_cfg, params)
     ex_state = ex.init_state()
+    # placed where the step returns its state: one type, one trace
+    params, opt_state, ex_state = jax.device_put(
+        (params, opt_state, ex_state), NamedSharding(mesh, P()))
     exchange_mod.wire_trace_start()
     mets = []
     with mesh:

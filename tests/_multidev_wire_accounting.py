@@ -30,7 +30,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 from repro.core.exchange import (  # noqa: E402
     ExchangeConfig,
@@ -67,7 +67,7 @@ def run_exchange(cfg, levels, mode, key):
 
         return shard_map(
             f, mesh=mesh, in_specs=(P("data", None), P()),
-            out_specs=P("data", None), check_rep=False,
+            out_specs=P("data", None), check_vma=False,
         )(x, k)
 
     return run(xs, key)

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import exchange_plan as xplan
@@ -82,7 +82,7 @@ def _run_tree(ex, tree, key, state=None):
     with mesh:
         out, new_st = jax.jit(shard_map(
             f, mesh=mesh, in_specs=(specs, P()),
-            out_specs=(specs, st_specs), check_rep=False,
+            out_specs=(specs, st_specs), check_vma=False,
         ))(tree, key)
     return out, new_st
 
@@ -149,7 +149,7 @@ def test_nb1_off_is_the_pr5_path(compressor, bits, mode):
         with mesh:
             return str(jax.make_jaxpr(shard_map(
                 f, mesh=mesh, in_specs=(specs, P()),
-                out_specs=(specs, st_specs), check_rep=False,
+                out_specs=(specs, st_specs), check_vma=False,
             ))(tree, KEY))
 
     assert mk(ex_e) == mk(ex_d)
@@ -331,7 +331,7 @@ def test_defer_tail_mask_rejected():
         with mesh:
             jax.jit(shard_map(
                 f, mesh=mesh, in_specs=(specs, P(), P()),
-                out_specs=specs, check_rep=False,
+                out_specs=specs, check_vma=False,
             ))(tree, KEY, jnp.ones((), jnp.float32))
 
 

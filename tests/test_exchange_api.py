@@ -22,12 +22,13 @@ Covers the redesign's contracts:
 import dataclasses
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import repro.core.exchange as exchange_mod
@@ -111,7 +112,7 @@ def test_exchange_matches_legacy_compressed_pmean(bits, mode, use_pallas):
             return mean
 
         return shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                         check_rep=False)(xl, key)
+                         check_vma=False)(xl, key)
 
     levels = uniform_levels(quant.num_levels)
 
@@ -123,7 +124,7 @@ def test_exchange_matches_legacy_compressed_pmean(bits, mode, use_pallas):
         )
         return shard_map(lambda a, k: f(a, key=k), mesh=mesh,
                          in_specs=(P(), P()), out_specs=P(),
-                         check_rep=False)(xl, key)
+                         check_vma=False)(xl, key)
 
     got = np.asarray(run_new(x, KEY))
     want = np.asarray(run_legacy(x, KEY))
@@ -167,7 +168,7 @@ def test_pmean_tree_matches_legacy_tree():
 
         return shard_map(f, mesh=mesh, in_specs=({"w": P(), "b": P()}, P()),
                          out_specs=({"w": P(), "b": P()},) * 2,
-                         check_rep=False)(t, key)
+                         check_vma=False)(t, key)
 
     new, old = run(tree, KEY)
     for k in tree:
@@ -222,7 +223,7 @@ def test_compressor_pmean_replicated_and_unbiased_1dev(name):
             return means, steps
 
         return shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=(P(), P()), check_rep=False)(xl, keys)
+                         out_specs=(P(), P()), check_vma=False)(xl, keys)
 
     outs, steps = run(x, jax.random.split(jax.random.PRNGKey(6), trials))
     assert int(np.asarray(steps)[-1]) == 1  # state threading: 1 call counted
@@ -407,7 +408,7 @@ def test_leafwise_allreduce_fallback_unbiased_and_counted():
                 return mean
 
             return shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=P(), check_rep=False)(t, key)
+                             out_specs=P(), check_vma=False)(t, key)
 
         outs[tag] = run(tree, KEY)
         rec = exchange_mod.wire_trace_stop()
@@ -444,7 +445,7 @@ def test_qada_refreshes_both_layerwise_tables():
             return st
 
         return shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                         check_rep=False)(xl, key)
+                         check_vma=False)(xl, key)
 
     st = run(x, KEY)
     assert int(st.step) == 1
@@ -557,3 +558,27 @@ def test_ef_error_memory_sizing():
     assert ex.init_state().error.shape == (1,)  # placeholder without args
     exq = make_exchange(_contract_config("randk"))
     assert exq.init_state(template=tree, num_workers=8).error.shape == (1,)
+
+
+def test_train_cli_one_device_compression_builds_exchange(capsys,
+                                                           monkeypatch,
+                                                           tmp_path):
+    """--compression int8 on ONE device is honoured: the launcher builds
+    the quantized exchange over the 1-device mesh (not a silent fp32
+    run), routes it through the Pallas kernels, and every step line
+    reports wire > 0."""
+    from repro.launch import train
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert jax.device_count() == 1
+    loss = train.main([
+        "--arch", "tinyllama-1.1b", "--reduced", "--steps", "2",
+        "--batch", "2", "--seq", "16", "--compression", "int8",
+        "--use-pallas",
+    ])
+    out = capsys.readouterr().out
+    assert np.isfinite(loss)
+    assert "[train] exchange: compressor=qgenx" in out
+    assert "use_pallas=True" in out
+    wires = [float(w) for w in re.findall(r"wire=(\S+)B", out)]
+    assert len(wires) == 2 and all(w > 0 for w in wires), out

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import exchange_plan as xplan
@@ -65,7 +65,7 @@ def _run_pmean_tree(ex, tree, key=KEY):
             return mean
 
         return shard_map(f, mesh=mesh, in_specs=(specs, P()),
-                         out_specs=specs, check_rep=False)(t, k)
+                         out_specs=specs, check_vma=False)(t, k)
 
     return go(tree, key)
 
@@ -231,7 +231,7 @@ def test_segment_fused_kernel_matches_per_segment_oracle():
         np.testing.assert_array_equal(np.asarray(fused[a:b]), np.asarray(want))
     # Pallas (interpret) == jnp reference, bit for bit
     got = quantize_dequantize_segments(
-        x, noise, tables, seg, num_symbols=nsym, q_is_inf=True, interpret=True)
+        x, noise, tables, seg, num_symbols=nsym, q_is_inf=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(fused))
 
 
@@ -245,7 +245,7 @@ def test_segment_fused_device_prng_traces():
     tables, nsym = xplan.stack_level_tables([uniform_levels(15)])
     f = functools.partial(
         quantize_dequantize_segments, num_symbols=nsym, q_is_inf=True,
-        use_device_prng=True, interpret=True,
+        use_device_prng=True,
     )
     out = jax.eval_shape(
         lambda a, t, s, sd: f(a, None, t, s, seed=sd),
@@ -298,7 +298,9 @@ def test_planned_compress_is_one_fused_invocation():
     tree = _tree()
     ex = make_exchange(cfg)
     text = str(jax.make_jaxpr(lambda t, k: ex.compress_tree(t, k))(tree, KEY))
-    assert text.count("pallas_call") == 1
+    # a kernel is staged once per platform branch (Mosaic on a TPU, the
+    # interpreter elsewhere): count the launches a TPU runs
+    assert text.count("interpret=False") == 1
     ex_legacy = make_exchange(dataclasses.replace(cfg, use_plan=False))
     legacy = str(jax.make_jaxpr(
         lambda t, k: ex_legacy.compress_tree(t, k))(tree, KEY))

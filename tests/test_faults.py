@@ -183,7 +183,7 @@ def test_guard_rejects_and_carries_state():
 def test_all_ones_mask_bit_exact_1dev():
     """mask=1.0 through a compressed pmean_tree is bitwise identical to
     mask=None (K=1 slice of the 8-dev parity grid)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
@@ -204,7 +204,7 @@ def test_all_ones_mask_bit_exact_1dev():
 
             return jax.jit(shard_map(
                 f, mesh=mesh, in_specs=({"w": P()}, P()),
-                out_specs={"w": P()}, check_rep=False,
+                out_specs={"w": P()}, check_vma=False,
             ))(tree, jax.random.PRNGKey(9))
 
         np.testing.assert_array_equal(

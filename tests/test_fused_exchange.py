@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.exchange import _qgenx_pmean
@@ -43,7 +43,7 @@ def _run(mode, bits, use_pallas, use_device_prng=False):
         )
         return shard_map(
             lambda a, k: f(a, key=k), mesh=mesh,
-            in_specs=(P(), P()), out_specs=P(), check_rep=False,
+            in_specs=(P(), P()), out_specs=P(), check_vma=False,
         )(xl, key)
 
     return run(x, jax.random.PRNGKey(11))
@@ -86,7 +86,7 @@ def test_device_prng_exchange_traces():
                 a, "data", levels, k, cfg, mode="two_phase",
                 use_pallas=True, use_device_prng=True,
             ),
-            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
         )(xl, key)
 
     out = jax.eval_shape(run, x, jax.random.PRNGKey(1))

@@ -50,7 +50,7 @@ os.environ["XLA_FLAGS"]="--xla_force_host_platform_device_count=4"
 import math
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.exchange import ExchangeConfig, make_exchange
 from repro.core.quantization import QuantConfig
 mesh = Mesh(np.array(jax.devices()).reshape(4), ("data",))
@@ -66,7 +66,7 @@ for bits, s in ((8, 15), (4, 5)):
             out, _ = EX.pmean_tree({"w": tl["w"][0]}, EX.init_state(), k)
             return {"w": out["w"][None]}
         return shard_map(f, mesh=mesh, in_specs=({"w": P("data",None,None)}, P()),
-                         out_specs={"w": P("data",None,None)}, check_rep=False)(t, key)
+                         out_specs={"w": P("data",None,None)}, check_vma=False)(t, key)
     acc = 0
     T = 40
     for t in range(T):

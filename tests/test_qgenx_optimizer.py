@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.core.exchange as exchange_mod
 import repro.core.extragradient as eg
@@ -351,7 +351,9 @@ def _run_steps(ex_cfg, n_steps, opt_name="extra_adam"):
     ex = make_exchange(ex_cfg)
     mesh = _one_dev_mesh()
     step = jax.jit(make_train_step(model, opt_cfg, exchange=ex, mesh=mesh))
-    ex_state = ex.init_state()
+    # placed where the step returns its state: one type, one trace
+    params, state, ex_state = jax.device_put(
+        (params, state, ex.init_state()), NamedSharding(mesh, P()))
     batch = _batch(jax.random.PRNGKey(1))
     out = []
     with mesh:

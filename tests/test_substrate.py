@@ -11,7 +11,7 @@ import pytest
 
 from repro.checkpoint import checkpointing
 from repro.configs.base import ShapeConfig
-from repro.configs.registry import get_config
+from repro.configs.registry import ARCHS, get_config
 from repro.data.pipeline import make_pipeline
 from repro.core.exchange import null_exchange_state
 from repro.launch.hlo_analysis import analyze_collectives
@@ -122,3 +122,34 @@ ENTRY %main (p: f32[8]) -> f32[8] {
     # wire estimates: AR 2*(3/4)*320 = 480; AG (3/4)*128 = 96
     assert abs(r["wire_bytes_by_kind"]["all-reduce"] - 480.0) < 1e-6
     assert abs(r["wire_bytes_by_kind"]["all-gather"] - 96.0) < 1e-6
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_full_configs_keep_their_dtype_and_reduced_is_float32(arch):
+    # the launchers run a config's own dtype; only the CPU smoke variant
+    # pins float32
+    cfg = get_config(arch)
+    assert cfg.dtype == "bfloat16"
+    assert cfg.reduced().dtype == "float32"
+
+
+def test_compilation_cache_dir_follows_env_else_fixed(monkeypatch, tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert cache.cache_dir() == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    # with the variable set jax reads it itself: the directory is the
+    # variable's and nothing is set in code
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+
+    def refuse(path):
+        raise AssertionError(f"cache dir set in code: {path}")
+
+    monkeypatch.setattr(compilation_cache, "set_cache_dir", refuse)
+    assert cache.enable_compilation_cache() == str(tmp_path / "c")
+    assert os.path.isdir(tmp_path / "c")
