@@ -354,6 +354,45 @@ def theorem2_bits_traced(pmf: Array, d, num_buckets) -> Array:
 # ---------------------------------------------------------------------------
 # Quantize / dequantize dispatch (Pallas kernels vs jnp reference)
 # ---------------------------------------------------------------------------
+#
+# Every qgenx exchange names its kernels with ``jax.named_scope`` so a device
+# trace can time each one: ``noise`` (the uniform draw of stochastic
+# rounding, or the device PRNG's seed), ``quantize`` (phase-1 quantize),
+# ``reduce`` (dequant-reduce(-requantize), Pallas or its jnp twin) and
+# ``dequantize`` (the final dequantize).  The scopes never nest, so each op
+# of the exchange belongs to at most one of them; everything else under
+# ``exchange`` is layout (pad, pack, reshape, unpack).
+
+
+def _rounding_noise(key, shape):
+    """The uniform draw of stochastic rounding, under the ``noise`` scope."""
+    with jax.named_scope("noise"):
+        return jax.random.uniform(key, shape, dtype=jnp.float32)
+
+
+def _quantize_with(x2d, noise, levels, cfg: QuantConfig, use_pallas: bool):
+    """[nb, bucket] f32 and its rounding noise -> (payload, norms)."""
+    q_is_inf = math.isinf(cfg.q_norm)
+    if use_pallas:
+        return quantize_blocks(
+            x2d, noise, levels,
+            num_symbols=cfg.num_symbols, q_is_inf=q_is_inf, bits=cfg.bits,
+        )
+    from repro.kernels.ref import quantize_blocks_ref
+
+    return quantize_blocks_ref(x2d, noise, levels, q_is_inf=q_is_inf, bits=cfg.bits)
+
+
+def _dequantize_with(payload2d, norms, levels, cfg: QuantConfig,
+                     use_pallas: bool):
+    """Wire payload [nb, P] -> [nb, bucket] f32 (unpacks in 4-bit mode)."""
+    if use_pallas:
+        return dequantize_blocks(
+            payload2d, norms, levels, num_symbols=cfg.num_symbols, bits=cfg.bits,
+        )
+    from repro.kernels.ref import dequantize_blocks_ref
+
+    return dequantize_blocks_ref(payload2d, norms, levels, bits=cfg.bits)
 
 
 def _quantize_2d(
@@ -372,7 +411,6 @@ def _quantize_2d(
     ``use_device_prng`` (Pallas on TPU) no host noise buffer is created:
     only a [1] int32 seed derived from ``key`` reaches the kernel.
     """
-    q_is_inf = math.isinf(cfg.q_norm)
     if use_device_prng and not use_pallas:
         raise ValueError(
             "use_device_prng requires use_pallas=True (the jnp reference "
@@ -380,33 +418,25 @@ def _quantize_2d(
             "full-size host noise buffer)"
         )
     if use_pallas and use_device_prng:
-        seed = derive_prng_seed(key)
-        return quantize_blocks(
-            x2d, None, levels,
-            num_symbols=cfg.num_symbols, q_is_inf=q_is_inf, bits=cfg.bits,
-            use_device_prng=True, seed=seed,
-        )
-    noise = jax.random.uniform(key, x2d.shape, dtype=jnp.float32)
-    if use_pallas:
-        return quantize_blocks(
-            x2d, noise, levels,
-            num_symbols=cfg.num_symbols, q_is_inf=q_is_inf, bits=cfg.bits,
-        )
-    from repro.kernels.ref import quantize_blocks_ref
-
-    return quantize_blocks_ref(x2d, noise, levels, q_is_inf=q_is_inf, bits=cfg.bits)
+        with jax.named_scope("noise"):
+            seed = derive_prng_seed(key)
+        with jax.named_scope("quantize"):
+            return quantize_blocks(
+                x2d, None, levels,
+                num_symbols=cfg.num_symbols, q_is_inf=math.isinf(cfg.q_norm),
+                bits=cfg.bits, use_device_prng=True, seed=seed,
+            )
+    noise = _rounding_noise(key, x2d.shape)
+    with jax.named_scope("quantize"):
+        return _quantize_with(x2d, noise, levels, cfg, use_pallas)
 
 
 def _dequantize_2d(payload2d, norms, levels, cfg: QuantConfig,
                    use_pallas: bool):
-    """Wire payload [nb, P] -> [nb, bucket] f32 (unpacks in 4-bit mode)."""
-    if use_pallas:
-        return dequantize_blocks(
-            payload2d, norms, levels, num_symbols=cfg.num_symbols, bits=cfg.bits,
-        )
-    from repro.kernels.ref import dequantize_blocks_ref
-
-    return dequantize_blocks_ref(payload2d, norms, levels, bits=cfg.bits)
+    """Wire payload [nb, P] -> [nb, bucket] f32, under the ``dequantize``
+    scope."""
+    with jax.named_scope("dequantize"):
+        return _dequantize_with(payload2d, norms, levels, cfg, use_pallas)
 
 
 def _axis_key(key: Array, axis_name, axis_index=None) -> Array:
@@ -473,17 +503,21 @@ def _qgenx_pmean(
         if use_pallas:
             # fused consumer: K payloads stream through VMEM, only the
             # final mean is written — no K intermediate f32 buffers.
-            mean2d = dequant_reduce_blocks(
-                all_p, all_norms, levels,
-                num_symbols=cfg.num_symbols, num_workers=axis_size, bits=cfg.bits,
-            )
+            with jax.named_scope("reduce"):
+                mean2d = dequant_reduce_blocks(
+                    all_p, all_norms, levels,
+                    num_symbols=cfg.num_symbols, num_workers=axis_size,
+                    bits=cfg.bits,
+                )
             return mean2d.reshape(-1)[:n]
-        deq = _dequantize_2d(
-            all_p.reshape(axis_size * nb, -1),
-            all_norms.reshape(axis_size * nb),
-            levels, cfg, use_pallas,
-        ).reshape(axis_size, nb * bucket)
-        return jnp.mean(deq, axis=0)[:n]
+        with jax.named_scope("reduce"):
+            deq = _dequantize_with(
+                all_p.reshape(axis_size * nb, -1),
+                all_norms.reshape(axis_size * nb),
+                levels, cfg, use_pallas,
+            ).reshape(axis_size, nb * bucket)
+            mean = jnp.mean(deq, axis=0)
+        return mean[:n]
 
     if mode == "two_phase":
         # pad so n splits into K chunks of whole buckets
@@ -510,28 +544,32 @@ def _qgenx_pmean(
             # the reduced f32 chunk never leaves VMEM.
             if use_device_prng:
                 noise2 = None
-                seed2 = derive_prng_seed(k2)
+                with jax.named_scope("noise"):
+                    seed2 = derive_prng_seed(k2)
             else:
-                noise2 = jax.random.uniform(k2, (nb_per_chunk, bucket), jnp.float32)
+                noise2 = _rounding_noise(k2, (nb_per_chunk, bucket))
                 seed2 = None
-            ridx, rnorms = dequant_reduce_requantize_blocks(
-                p_t, n_t, levels, noise2,
-                num_symbols=cfg.num_symbols, num_workers=axis_size,
-                q_is_inf=math.isinf(cfg.q_norm), bits=cfg.bits,
-                use_device_prng=use_device_prng, seed=seed2,
-            )
+            with jax.named_scope("reduce"):
+                ridx, rnorms = dequant_reduce_requantize_blocks(
+                    p_t, n_t, levels, noise2,
+                    num_symbols=cfg.num_symbols, num_workers=axis_size,
+                    q_is_inf=math.isinf(cfg.q_norm), bits=cfg.bits,
+                    use_device_prng=use_device_prng, seed=seed2,
+                )
         else:
-            deq = _dequantize_2d(
-                p_t.reshape(axis_size * nb_per_chunk, -1),
-                n_t.reshape(axis_size * nb_per_chunk),
-                levels, cfg, use_pallas,
-            ).reshape(axis_size, chunk)
-            reduced = jnp.mean(deq, axis=0)  # this device's chunk of the mean
-            # re-quantize (unbiased) and share the reduced chunk
-            r2d = reduced.reshape(nb_per_chunk, bucket)
-            ridx, rnorms = _quantize_2d(
-                r2d, levels, k2, cfg, use_pallas
-            )
+            # the jnp twin of the fused kernel, with the same noise draw
+            noise2 = _rounding_noise(k2, (nb_per_chunk, bucket))
+            with jax.named_scope("reduce"):
+                deq = _dequantize_with(
+                    p_t.reshape(axis_size * nb_per_chunk, -1),
+                    n_t.reshape(axis_size * nb_per_chunk),
+                    levels, cfg, use_pallas,
+                ).reshape(axis_size, chunk)
+                reduced = jnp.mean(deq, axis=0)  # this device's chunk of the mean
+                # re-quantize (unbiased) and share the reduced chunk
+                r2d = reduced.reshape(nb_per_chunk, bucket)
+                ridx, rnorms = _quantize_with(r2d, noise2, levels, cfg,
+                                              use_pallas)
         _record_wire("gather_payload", ridx)
         _record_wire("gather_norms", rnorms)
         g_idx = jax.lax.all_gather(ridx, axis_name, tiled=True)
@@ -542,15 +580,15 @@ def _qgenx_pmean(
     raise ValueError(f"unknown mode {mode!r}")
 
 
-def _round_indices_select(u: Array, levels: Array, key: Array,
-                          stochastic: bool) -> Array:
+def _round_indices_select(u: Array, levels: Array,
+                          noise: Optional[Array]) -> Array:
     """Gather-free twin of ``quantization._stochastic_round_indices``:
-    bracket via :func:`_bracket_select` — same noise draw, bit-identical
-    indices."""
+    bracket via :func:`_bracket_select` — given the same noise draw
+    (``jax.random.uniform(key, u.shape)``; None rounds to nearest),
+    bit-identical indices."""
     tau, _, _, xi = _bracket_select(u, levels)
-    if stochastic:
-        r = jax.random.uniform(key, u.shape, dtype=u.dtype)
-        up = (r < xi).astype(jnp.int32)
+    if noise is not None:
+        up = (noise < xi).astype(jnp.int32)
     else:
         up = (xi >= 0.5).astype(jnp.int32)
     return tau + up
@@ -587,26 +625,29 @@ def _qgenx_pmean_leafwise(
     out = []
     lv = levels.astype(jnp.float32)
     for g, k in zip(leaves, keys):
-        gf = g.astype(jnp.float32)
-        if math.isinf(cfg.q_norm):
-            norms = jnp.max(jnp.abs(gf), axis=-1, keepdims=True)
-        else:
-            norms = jnp.sqrt(jnp.sum(gf * gf, axis=-1, keepdims=True))
-        safe = jnp.where(norms > 0, norms, 1.0)
-        u = jnp.clip(jnp.abs(gf) / safe, 0.0, 1.0)
-        # gather-free rounding/dequant lookups: this is the exchange the
-        # partially-manual production mesh runs (bit-identical to the
-        # quantization-module oracle; see _round_indices_select)
-        idx = _round_indices_select(u, lv, k, cfg.stochastic)
-        signed = jnp.where(gf < 0, -idx, idx)
+        noise = _rounding_noise(k, g.shape) if cfg.stochastic else None
+        with jax.named_scope("quantize"):
+            gf = g.astype(jnp.float32)
+            if math.isinf(cfg.q_norm):
+                norms = jnp.max(jnp.abs(gf), axis=-1, keepdims=True)
+            else:
+                norms = jnp.sqrt(jnp.sum(gf * gf, axis=-1, keepdims=True))
+            safe = jnp.where(norms > 0, norms, 1.0)
+            u = jnp.clip(jnp.abs(gf) / safe, 0.0, 1.0)
+            # gather-free rounding/dequant lookups: this is the exchange
+            # the partially-manual production mesh runs (bit-identical to
+            # the quantization-module oracle; see _round_indices_select)
+            idx = _round_indices_select(u, lv, noise)
+            signed = jnp.where(gf < 0, -idx, idx)
         if allreduce_fallback:
             # partially-manual meshes lower ONLY all-reduce (see
             # ExchangeConfig.allreduce_fallback): dequantize the OWN
             # payload locally — identical rounding noise, identical
             # unbiased mean — and psum the f32 estimate.  The f32 operand
             # IS the wire payload here; record it as such.
-            hat = (_select_gather(lv, jnp.abs(signed))
-                   * jnp.sign(gf) * norms)
+            with jax.named_scope("dequantize"):
+                hat = (_select_gather(lv, jnp.abs(signed))
+                       * jnp.sign(gf) * norms)
             _record_wire("leaf_fallback", hat)
             axis_size = jax.lax.psum(1, axis_name)
             out.append((jax.lax.psum(hat, axis_name) / axis_size)
@@ -616,26 +657,30 @@ def _qgenx_pmean_leafwise(
         # (packing reuses the kernels' wire-format helpers — one layout)
         d = g.shape[-1]
         pack4 = cfg.bits == 4 and d % 2 == 0
-        if pack4:
-            payload = pack4_rows(signed.reshape(-1, d)).reshape(
-                g.shape[:-1] + (d // 2,)
-            )
-        else:
-            payload = signed.astype(jnp.int8)
+        with jax.named_scope("quantize"):
+            if pack4:
+                payload = pack4_rows(signed.reshape(-1, d)).reshape(
+                    g.shape[:-1] + (d // 2,)
+                )
+            else:
+                payload = signed.astype(jnp.int8)
         _record_wire("leaf_payload", payload)
         _record_wire("leaf_norms", norms)
         all_p = jax.lax.all_gather(payload, axis_name)  # [K, ...]
         all_norms = jax.lax.all_gather(norms, axis_name)
-        if pack4:
-            all_idx = unpack4_rows(all_p.reshape(-1, d // 2)).reshape(
-                all_p.shape[:-1] + (d,)
-            )
-        else:
-            all_idx = all_p.astype(jnp.int32)
-        mag = jnp.abs(all_idx)
-        vals = (_select_gather(lv, mag)
-                * jnp.sign(all_idx.astype(jnp.float32)) * all_norms)
-        out.append(jnp.mean(vals, axis=0).astype(g.dtype))
+        # every worker's payload dequantized and averaged: the reduce
+        with jax.named_scope("reduce"):
+            if pack4:
+                all_idx = unpack4_rows(all_p.reshape(-1, d // 2)).reshape(
+                    all_p.shape[:-1] + (d,)
+                )
+            else:
+                all_idx = all_p.astype(jnp.int32)
+            mag = jnp.abs(all_idx)
+            vals = (_select_gather(lv, mag)
+                    * jnp.sign(all_idx.astype(jnp.float32)) * all_norms)
+            mean = jnp.mean(vals, axis=0)
+        out.append(mean.astype(g.dtype))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -1112,11 +1157,10 @@ class Compressor:
             # Definition-1 unbiased; num_buckets=1 never reaches here,
             # so the monolithic jaxpr keeps its exact keys)
             bkey = jax.random.fold_in(key, bi)
-            with jax.named_scope(f"exchange/bucket{bi}"):
+            with jax.named_scope(f"bucket{bi}"):
                 with jax.named_scope("pack"):
                     flat = plan.pack(sub)
-                with wire_scope(f"b{bi}/"), \
-                        jax.named_scope("quantize_collective"):
+                with wire_scope(f"b{bi}/"):
                     mean_flat = self._pmean_planned(
                         flat, plan, cfg, state, bkey, axis_index
                     )
@@ -1386,16 +1430,18 @@ class RandKCompressor(Compressor):
         k = _randk_k(n, cfg)
         key = _axis_key(key, cfg.axis_name, axis_index)
         axis_size = jax.lax.psum(1, cfg.axis_name)
-        idx = self._support(n, k, key).astype(jnp.int32)
-        vals = x[idx] * (n / k)
+        with jax.named_scope("quantize"):
+            idx = self._support(n, k, key).astype(jnp.int32)
+            vals = x[idx] * (n / k)
         _record_wire("randk_vals", vals)
         _record_wire("randk_idx", idx)
         all_vals = jax.lax.all_gather(vals, cfg.axis_name)  # [K, k] f32
         all_idx = jax.lax.all_gather(idx, cfg.axis_name)  # [K, k] i32
-        out = jnp.zeros((n,), jnp.float32).at[all_idx.reshape(-1)].add(
-            all_vals.reshape(-1)
-        )
-        return out / axis_size
+        with jax.named_scope("dequantize"):
+            out = jnp.zeros((n,), jnp.float32).at[all_idx.reshape(-1)].add(
+                all_vals.reshape(-1)
+            )
+            return out / axis_size
 
     def compress(self, v, cfg, levels, key):
         n = v.shape[0]
@@ -1504,20 +1550,22 @@ class _ErrorFeedbackCompressor(Compressor):
         key = _axis_key(key, cfg.axis_name, axis_index)
         row = (axis_index if axis_index is not None
                else jax.lax.axis_index(cfg.axis_name))
-        innov = flat.astype(jnp.float32) - h[row]
-        idx = self._support(innov, k, cfg, key).astype(jnp.int32)
-        vals = innov[idx]
+        with jax.named_scope("quantize"):
+            innov = flat.astype(jnp.float32) - h[row]
+            idx = self._support(innov, k, cfg, key).astype(jnp.int32)
+            vals = innov[idx]
         _record_wire(f"{self.wire_tag}_vals", vals)
         _record_wire(f"{self.wire_tag}_idx", idx)
         all_vals = jax.lax.all_gather(vals, cfg.axis_name)  # [K, k] f32
         all_idx = jax.lax.all_gather(idx, cfg.axis_name)  # [K, k] i32
         # every device replays ALL workers' innovations so the [K, n]
         # memory stays replicated across the exchange axis
-        row_off = jnp.arange(num_workers, dtype=jnp.int32)[:, None] * n
-        h_new = h.reshape(-1).at[(all_idx + row_off).reshape(-1)].add(
-            all_vals.reshape(-1)
-        ).reshape(num_workers, n)
-        return jnp.mean(h_new, axis=0), h_new
+        with jax.named_scope("dequantize"):
+            row_off = jnp.arange(num_workers, dtype=jnp.int32)[:, None] * n
+            h_new = h.reshape(-1).at[(all_idx + row_off).reshape(-1)].add(
+                all_vals.reshape(-1)
+            ).reshape(num_workers, n)
+            return jnp.mean(h_new, axis=0), h_new
 
     def pmean_ef(self, x, cfg, state, key, axis_index=None):
         return self._ef_exchange(x, cfg, state.error, key, axis_index)
@@ -1825,6 +1873,21 @@ def _renorm_tree(tree, renorm: Array):
 # ---------------------------------------------------------------------------
 
 
+def _exchange_scope(method):
+    """Run an Exchange entry point under the ``exchange`` named scope.
+
+    Every path (monolithic, bucketed, leafwise, error feedback) goes
+    through one of the scoped entry points exactly once, so a device
+    trace finds each exchange op under one ``exchange/`` and nothing else
+    carries that name; entry points call each other's unscoped bodies."""
+    @functools.wraps(method)
+    def scoped(self, *args, **kwargs):
+        with jax.named_scope("exchange"):
+            return method(self, *args, **kwargs)
+
+    return scoped
+
+
 class Exchange:
     """A configured exchange: compressor + state management + accounting.
 
@@ -1961,6 +2024,7 @@ class Exchange:
 
     # -- exchanges -----------------------------------------------------
 
+    @_exchange_scope
     def pmean(self, x: Array, state: ExchangeState, key: Array,
               axis_index=None, mask: Optional[Array] = None):
         """Unbiased mean of a flat vector over the exchange axis.
@@ -1997,6 +2061,7 @@ class Exchange:
         hist = self._flat_hist(x) if self._qada_active() else None
         return self._finish(mean, state, hist, mask)
 
+    @_exchange_scope
     def pmean_tree(self, tree, state: ExchangeState, key: Array,
                    axis_index=None, mask: Optional[Array] = None):
         """Unbiased mean of a gradient pytree (bucket-fused / per policy).
@@ -2004,7 +2069,7 @@ class Exchange:
         ``mask`` excludes this device from the aggregate (renormalized
         over the alive set — see :meth:`pmean`)."""
         if self.cfg.mode == "leafwise":
-            return self.pmean_leafwise(tree, state, key, axis_index, mask)
+            return self._pmean_leafwise(tree, state, key, axis_index, mask)
         if self.compressor.has_error:
             self._reject_mask(mask)
             mean, err = self.compressor.pmean_tree_ef(
@@ -2036,8 +2101,8 @@ class Exchange:
         hist = self._tree_hist(tree) if self._qada_active() else None
         return self._finish(mean, state, hist, mask)
 
-    def pmean_leafwise(self, tree, state: ExchangeState, key: Array,
-                       axis_index=None, mask: Optional[Array] = None):
+    def _pmean_leafwise(self, tree, state: ExchangeState, key: Array,
+                        axis_index=None, mask: Optional[Array] = None):
         """Sharding-preserving per-leaf exchange (production mesh)."""
         cfg = dataclasses.replace(self.cfg, mode="leafwise")
         self.compressor.validate(cfg)  # loud, not a silent flat fallback
@@ -2046,6 +2111,8 @@ class Exchange:
         mean = self.compressor.pmean_tree(tree, cfg, state, key, axis_index)
         hist = self._leafwise_hist(tree) if self._qada_active() else None
         return self._finish(mean, state, hist, mask)
+
+    pmean_leafwise = _exchange_scope(_pmean_leafwise)
 
     def _reject_mask(self, mask):
         """Error feedback + partial participation is undefined here: a

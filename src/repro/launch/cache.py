@@ -12,11 +12,13 @@ stay flag-thin:
 
 * :func:`profile_trace` — a context manager around
   ``jax.profiler.start_trace`` / ``stop_trace`` emitting a TensorBoard-
-  loadable trace.  The exchange annotates its bucketed pipeline with
-  ``jax.named_scope`` (``exchange/bucket{i}/{pack,quantize_collective,
-  unpack}``) and the staged backward with ``staged_forward`` /
-  ``staged_backward``, so communication/compute overlap is visible per
-  bucket in the trace viewer (workflow documented in DESIGN.md §10).
+  loadable trace.  The train step names its stages with
+  ``jax.named_scope`` — each oracle round (``lookahead``, ``commit``) with
+  ``forward``, ``backward``, ``update`` and the ``exchange`` inside it,
+  whose kernels are ``noise``, ``quantize``, ``reduce`` and
+  ``dequantize``; then ``step_metrics`` and ``guard`` — so
+  communication/compute overlap is visible per bucket in the trace viewer
+  (workflow documented in DESIGN.md §10).
 
 Both are failure-tolerant by design: a launcher must never die because a
 cache directory is read-only or a profiler backend is missing — the

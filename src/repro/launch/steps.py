@@ -196,8 +196,8 @@ def make_train_step(
     if ex is not None and ex.cfg.overlap != "off":
         # Bucketed overlapped exchange: stage the backward explicitly
         # through jax.vjp (numerically identical to value_and_grad — the
-        # same cotangent pullback seeded with 1.0) and fence forward /
-        # backward in named_scopes so traces show the overlap.  The
+        # same cotangent pullback seeded with 1.0) under the ``forward``
+        # and ``backward`` scopes, so traces show the overlap.  The
         # overlap itself is a DATA-FLOW property, not a Python-order one:
         # each bucket's quantize+collective chain (issued inside
         # ex.pmean_tree, highest-leaf buckets first — the cotangents
@@ -207,12 +207,14 @@ def make_train_step(
         # earlier layers is still in flight, instead of serializing one
         # monolithic gather behind the full gradient.
         def grad_fn(p, b):
-            with jax.named_scope("staged_forward"):
+            with jax.named_scope("forward"):
                 loss, pullback = jax.vjp(lambda q: loss_fn(q, b), p)
-            with jax.named_scope("staged_backward"):
+            with jax.named_scope("backward"):
                 (g,) = pullback(jnp.ones_like(loss))
             return loss, g
     else:
+        # unstaged: the round's scope holds both passes, and jax names
+        # the backward's ops ``transpose(jvp(...))`` inside it
         grad_fn = jax.value_and_grad(loss_fn)
     axis_name = ex.cfg.axis_name if ex is not None else None
     sync_every = ex.cfg.sync_every if ex is not None else 1
@@ -291,76 +293,107 @@ def make_train_step(
             )
 
         n_workers = jax.lax.psum(1, axis_name) if ex is not None else 1
+        # Each oracle round runs under its own scope — ``lookahead`` (the
+        # first gradient, its exchange, the extrapolation) and ``commit``
+        # (the second gradient, its exchange, the update) — with the
+        # optimizer's arithmetic under ``update`` inside it; one-call
+        # branches have ``commit`` alone.  The device-trace readers of
+        # bench/ split the step by these names.
+        scope = jax.named_scope
         if opt_cfg.name == "extra_adam":
-            loss1, g1 = gfn(params, batch)
-            g1, ex_state = exchange_grads(g1, ex_state, k1)
-            params_half = opt.extrapolate(opt_cfg, params, opt_state, g1)
-            loss, g2 = gfn(params_half, batch)
-            g2, ex_state = exchange_grads(g2, ex_state, k2)
-            new_params, new_state = opt.commit(opt_cfg, params, opt_state, g2)
+            with scope("lookahead"):
+                loss1, g1 = gfn(params, batch)
+                g1, ex_state = exchange_grads(g1, ex_state, k1)
+                with scope("update"):
+                    params_half = opt.extrapolate(opt_cfg, params, opt_state,
+                                                  g1)
+            with scope("commit"):
+                loss, g2 = gfn(params_half, batch)
+                g2, ex_state = exchange_grads(g2, ex_state, k2)
+                with scope("update"):
+                    new_params, new_state = opt.commit(opt_cfg, params,
+                                                       opt_state, g2)
         elif opt_cfg.name == "qgenx" and get_method(opt_cfg.method).uses_prev_half:
             # optda (Example 3.3): the extrapolation feedback is the
             # PREVIOUS half-step exchanged mean carried in the optimizer
             # state — one oracle call and one broadcast round per step
-            ghat1 = opt_state.prev_half
-            params_half = qgenx_opt.extrapolate(
-                opt_cfg, params, opt_state, ghat1, n_workers
-            )
-            loss, g2 = gfn(params_half, batch)
-            ghat2, ex_state = exchange_grads(g2, ex_state, k2)
-            # sum_k ||Vbar_{t} - g_{k,t+1/2}||^2 — the carried feedback vs
-            # this worker's fresh half-step oracle (at K=1 uncompressed
-            # this is exactly the toy optda statistic; parity-tested).
-            # Under a CONTRACTIVE compressor the raw local gradient is
-            # not a proxy for the estimate the recursion applies, so the
-            # gamma statistic uses the compensated (exchanged) estimate
-            # instead — Python-gated to keep the unbiased jaxpr bit-exact.
-            if ex is not None and ex.compressor.has_error:
-                sq = qgenx_opt.local_sq_diff(ghat1, ghat2)
-            else:
-                sq = qgenx_opt.local_sq_diff(ghat1, g2)
-            if ex is not None:
-                sq = jax.lax.psum(sq, axis_name)
-            new_params, new_state = qgenx_opt.commit(
-                opt_cfg, params, opt_state, ghat2, sq, n_workers,
-                prev_half=ghat2,
-            )
+            with scope("commit"):
+                ghat1 = opt_state.prev_half
+                with scope("update"):
+                    params_half = qgenx_opt.extrapolate(
+                        opt_cfg, params, opt_state, ghat1, n_workers
+                    )
+                loss, g2 = gfn(params_half, batch)
+                ghat2, ex_state = exchange_grads(g2, ex_state, k2)
+                with scope("update"):
+                    # sum_k ||Vbar_{t} - g_{k,t+1/2}||^2 — the carried
+                    # feedback vs this worker's fresh half-step oracle (at
+                    # K=1 uncompressed this is exactly the toy optda
+                    # statistic; parity-tested).  Under a CONTRACTIVE
+                    # compressor the raw local gradient is not a proxy for
+                    # the estimate the recursion applies, so the gamma
+                    # statistic uses the compensated (exchanged) estimate
+                    # instead — Python-gated to keep the unbiased jaxpr
+                    # bit-exact.
+                    if ex is not None and ex.compressor.has_error:
+                        sq = qgenx_opt.local_sq_diff(ghat1, ghat2)
+                    else:
+                        sq = qgenx_opt.local_sq_diff(ghat1, g2)
+                    if ex is not None:
+                        sq = jax.lax.psum(sq, axis_name)
+                    new_params, new_state = qgenx_opt.commit(
+                        opt_cfg, params, opt_state, ghat2, sq, n_workers,
+                        prev_half=ghat2,
+                    )
             g2 = ghat2  # for the wire accounting below (same tree shapes)
         elif opt_cfg.name == "qgenx":
             # de (Example 3.2) — the paper's Algorithm 1 on the model:
             # extragradient with the adaptive gamma rule (statistics in
             # the QGenXOptState pytree)
-            loss1, g1 = gfn(params, batch)
-            ghat1, ex_state = exchange_grads(g1, ex_state, k1)
-            params_half = qgenx_opt.extrapolate(
-                opt_cfg, params, opt_state, ghat1, n_workers
-            )
-            loss, g2 = gfn(params_half, batch)
-            ghat2, ex_state = exchange_grads(g2, ex_state, k2)
-            # sum_k ||g_{k,t} - g_{k,t+1/2}||^2 — the gamma-rule statistic
-            # (from the raw local oracles; under a contractive compressor
-            # the COMPENSATED estimates replace them — the locals are not
-            # a proxy for what the EF recursion actually applies)
-            if ex is not None and ex.compressor.has_error:
-                sq = qgenx_opt.local_sq_diff(ghat1, ghat2)
-            else:
-                sq = qgenx_opt.local_sq_diff(g1, g2)
-            if ex is not None:
-                sq = jax.lax.psum(sq, axis_name)
-            new_params, new_state = qgenx_opt.commit(
-                opt_cfg, params, opt_state, ghat2, sq, n_workers
-            )
+            with scope("lookahead"):
+                loss1, g1 = gfn(params, batch)
+                ghat1, ex_state = exchange_grads(g1, ex_state, k1)
+                with scope("update"):
+                    params_half = qgenx_opt.extrapolate(
+                        opt_cfg, params, opt_state, ghat1, n_workers
+                    )
+            with scope("commit"):
+                loss, g2 = gfn(params_half, batch)
+                ghat2, ex_state = exchange_grads(g2, ex_state, k2)
+                with scope("update"):
+                    # sum_k ||g_{k,t} - g_{k,t+1/2}||^2 — the gamma-rule
+                    # statistic (from the raw local oracles; under a
+                    # contractive compressor the COMPENSATED estimates
+                    # replace them — the locals are not a proxy for what
+                    # the EF recursion actually applies)
+                    if ex is not None and ex.compressor.has_error:
+                        sq = qgenx_opt.local_sq_diff(ghat1, ghat2)
+                    else:
+                        sq = qgenx_opt.local_sq_diff(g1, g2)
+                    if ex is not None:
+                        sq = jax.lax.psum(sq, axis_name)
+                    new_params, new_state = qgenx_opt.commit(
+                        opt_cfg, params, opt_state, ghat2, sq, n_workers
+                    )
             g2 = ghat2  # for the wire accounting below (same tree shapes)
         elif opt_cfg.name == "optimistic_adam":
-            prev = opt_state.prev_half_grad
-            params_half = opt.extrapolate(opt_cfg, params, opt_state, prev)
-            loss, g2 = gfn(params_half, batch)
-            g2, ex_state = exchange_grads(g2, ex_state, k2)
-            new_params, new_state = opt.commit(opt_cfg, params, opt_state, g2)
+            with scope("commit"):
+                prev = opt_state.prev_half_grad
+                with scope("update"):
+                    params_half = opt.extrapolate(opt_cfg, params, opt_state,
+                                                  prev)
+                loss, g2 = gfn(params_half, batch)
+                g2, ex_state = exchange_grads(g2, ex_state, k2)
+                with scope("update"):
+                    new_params, new_state = opt.commit(opt_cfg, params,
+                                                       opt_state, g2)
         else:  # adam baseline
-            loss, g2 = gfn(params, batch)
-            g2, ex_state = exchange_grads(g2, ex_state, k2)
-            new_params, new_state = opt.adam_step(opt_cfg, params, opt_state, g2)
+            with scope("commit"):
+                loss, g2 = gfn(params, batch)
+                g2, ex_state = exchange_grads(g2, ex_state, k2)
+                with scope("update"):
+                    new_params, new_state = opt.adam_step(opt_cfg, params,
+                                                          opt_state, g2)
 
         st_grad = ex_state  # state after the GRADIENT exchanges only —
         # the re-centering exchange below moves a params/Y-shaped tree
@@ -374,83 +407,88 @@ def make_train_step(
             # trade drift for wire.  For qgenx the dual accumulator Y is
             # the iterate (X = anchor + gamma Y with anchor/gamma
             # replicated), so re-centering Y re-centers X consistently;
-            # the adam family re-centers the params directly.
-            is_rc = (opt_state.count % recenter_every) == (recenter_every - 1)
-            k3 = jax.random.fold_in(key, 0x5eed)  # disjoint from split(key)
+            # the adam family re-centers the params directly.  It ends
+            # the commit round: its exchange and arithmetic are the update's.
+            with scope("commit"), scope("update"):
+                is_rc = (opt_state.count % recenter_every) == (recenter_every - 1)
+                k3 = jax.random.fold_in(key, 0x5eed)  # disjoint from split(key)
 
-            if opt_cfg.name == "qgenx":
-                def _recenter(args):
-                    p, st, exst = args
-                    y_bar, exst = ex.pmean_tree(st.y, exst, k3, ix, mask=mask)
-                    gamma = adaptive_gamma(
-                        st.sum_sq, n_workers, opt_cfg.gamma_scale
-                    )
-                    p = commit_params(st.anchor, y_bar, gamma, like=p)
-                    return p, st._replace(y=y_bar), exst
-            else:
-                def _recenter(args):
-                    p, st, exst = args
-                    p_bar, exst = ex.pmean_tree(p, exst, k3, ix, mask=mask)
-                    return p_bar, st, exst
-
-            new_params, new_state, ex_state = jax.lax.cond(
-                is_rc, _recenter, lambda args: args,
-                (new_params, new_state, ex_state),
-            )
-        drift = jnp.float32(0.0)
-        coded = jnp.float32(0.0)
-        alive_m = jnp.float32(1.0)
-        if ex is not None:
-            loss = jax.lax.pmean(loss, axis_name)  # replicated metric
-            # analytic per-exchange operand bytes (static shapes) times the
-            # number of exchanges this step performed (= step counter delta;
-            # 0 on non-sync steps under the local-update regime; the
-            # re-centering exchange bumps the counter too, so its bytes
-            # are counted by the same formula)
-            axis_size = jax.lax.psum(1, axis_name)
-            per_call = ex.wire_bytes_tree(g2, axis_size)
-            n_calls = (ex_state.step - st_in.step).astype(jnp.float32)
-            wire = jnp.float32(per_call) * n_calls
-            alive_m = jnp.float32(axis_size)
-            if mask is not None:
-                # partial participation: only alive workers transmit — the
-                # fleet's wire bill this step is alive/K of the full one.
-                # (coded_bits_est stays per-worker/unscaled by design: it
-                # estimates what ONE worker's broadcasts would entropy-code
-                # to, not fleet traffic.)
-                alive_m = jax.lax.psum(mask, axis_name)
-                wire = wire * (alive_m / jnp.float32(axis_size))
-            # Theorem 2 entropy-coded wire estimate (Section 3.2): what
-            # one worker's GRADIENT broadcasts would cost under CODE o Q
-            # with an optimal prefix code, alongside the fixed-width
-            # wire_bytes actually shipped — per-call x n_grad_calls.
-            # The O(n) pmf pass is gated like the drift probe: under the
-            # local-update regime it only runs on sync steps (its result
-            # would be multiplied by a traced zero otherwise, which XLA
-            # cannot eliminate).
-            if ex.cfg.compressor == "qgenx":
-                n_grad_calls = (st_grad.step - st_in.step).astype(jnp.float32)
-                if is_sync is None:
-                    coded_per = ex.coded_bits_tree(g2, st_in)
+                if opt_cfg.name == "qgenx":
+                    def _recenter(args):
+                        p, st, exst = args
+                        y_bar, exst = ex.pmean_tree(st.y, exst, k3, ix, mask=mask)
+                        gamma = adaptive_gamma(
+                            st.sum_sq, n_workers, opt_cfg.gamma_scale
+                        )
+                        p = commit_params(st.anchor, y_bar, gamma, like=p)
+                        return p, st._replace(y=y_bar), exst
                 else:
-                    coded_per = jax.lax.cond(
-                        is_sync,
-                        lambda g: ex.coded_bits_tree(g, st_in),
-                        lambda g: jnp.float32(0.0),
-                        g2,
-                    )
-                coded = coded_per * n_grad_calls
-            if is_sync is not None:
-                # drift probe: measured (and paid) only on sync steps —
-                # params provably stay replicated when every step syncs
-                drift = jax.lax.cond(
-                    is_sync, _param_drift, lambda p: jnp.float32(0.0), params
+                    def _recenter(args):
+                        p, st, exst = args
+                        p_bar, exst = ex.pmean_tree(p, exst, k3, ix, mask=mask)
+                        return p_bar, st, exst
+
+                new_params, new_state, ex_state = jax.lax.cond(
+                    is_rc, _recenter, lambda args: args,
+                    (new_params, new_state, ex_state),
                 )
-                n = sum(l.size for l in jax.tree_util.tree_leaves(params))
-                probe_bytes = 4.0 * min(ex.cfg.drift_probe, n)
-                wire = wire + jnp.float32(probe_bytes) * is_sync.astype(jnp.float32)
-        else:
-            wire = jnp.float32(0.0)
+        # the step's metrics: loss mean, wire accounting, Theorem-2
+        # code length, drift probe
+        with scope("step_metrics"):
+            drift = jnp.float32(0.0)
+            coded = jnp.float32(0.0)
+            alive_m = jnp.float32(1.0)
+            if ex is not None:
+                loss = jax.lax.pmean(loss, axis_name)  # replicated metric
+                # analytic per-exchange operand bytes (static shapes) times the
+                # number of exchanges this step performed (= step counter delta;
+                # 0 on non-sync steps under the local-update regime; the
+                # re-centering exchange bumps the counter too, so its bytes
+                # are counted by the same formula)
+                axis_size = jax.lax.psum(1, axis_name)
+                per_call = ex.wire_bytes_tree(g2, axis_size)
+                n_calls = (ex_state.step - st_in.step).astype(jnp.float32)
+                wire = jnp.float32(per_call) * n_calls
+                alive_m = jnp.float32(axis_size)
+                if mask is not None:
+                    # partial participation: only alive workers transmit — the
+                    # fleet's wire bill this step is alive/K of the full one.
+                    # (coded_bits_est stays per-worker/unscaled by design: it
+                    # estimates what ONE worker's broadcasts would entropy-code
+                    # to, not fleet traffic.)
+                    alive_m = jax.lax.psum(mask, axis_name)
+                    wire = wire * (alive_m / jnp.float32(axis_size))
+                # Theorem 2 entropy-coded wire estimate (Section 3.2): what
+                # one worker's GRADIENT broadcasts would cost under CODE o Q
+                # with an optimal prefix code, alongside the fixed-width
+                # wire_bytes actually shipped — per-call x n_grad_calls.
+                # The O(n) pmf pass is gated like the drift probe: under the
+                # local-update regime it only runs on sync steps (its result
+                # would be multiplied by a traced zero otherwise, which XLA
+                # cannot eliminate).
+                if ex.cfg.compressor == "qgenx":
+                    n_grad_calls = (st_grad.step - st_in.step).astype(jnp.float32)
+                    if is_sync is None:
+                        coded_per = ex.coded_bits_tree(g2, st_in)
+                    else:
+                        coded_per = jax.lax.cond(
+                            is_sync,
+                            lambda g: ex.coded_bits_tree(g, st_in),
+                            lambda g: jnp.float32(0.0),
+                            g2,
+                        )
+                    coded = coded_per * n_grad_calls
+                if is_sync is not None:
+                    # drift probe: measured (and paid) only on sync steps —
+                    # params provably stay replicated when every step syncs
+                    drift = jax.lax.cond(
+                        is_sync, _param_drift, lambda p: jnp.float32(0.0), params
+                    )
+                    n = sum(l.size for l in jax.tree_util.tree_leaves(params))
+                    probe_bytes = 4.0 * min(ex.cfg.drift_probe, n)
+                    wire = wire + jnp.float32(probe_bytes) * is_sync.astype(jnp.float32)
+            else:
+                wire = jnp.float32(0.0)
         rejected = jnp.float32(0.0)
         nonfin = jnp.float32(0.0)
         if guard:
@@ -462,32 +500,33 @@ def make_train_step(
             # exchange-call counter (sync_every gating, QAda hist/refresh
             # cadence, recenter cadence) and, for optda, keeps the
             # pre-step prev_half feedback.
-            ok_local = faults_mod.tree_all_finite(
-                loss, new_params, new_state, ex_state
-            )
-            bad = (~ok_local).astype(jnp.float32)
-            if ex is not None:
-                # a dropped worker cannot veto the fleet's step (its local
-                # candidate never entered the aggregate), but it still
-                # shows up in the nonfinite diagnostic
-                bad_alive = bad * mask if mask is not None else bad
-                nonfin_any = jax.lax.psum(bad, axis_name)
-                ok = jax.lax.psum(bad_alive, axis_name) == 0
-            else:
-                nonfin_any = bad
-                ok = bad == 0
-            nonfin = (nonfin_any > 0).astype(jnp.float32)
-            new_params, new_state, ex_state = jax.lax.cond(
-                ok,
-                lambda t: (t[0], t[1], t[2]),
-                lambda t: (t[3], t[4], t[5]),
-                (new_params, new_state, ex_state, params, opt_state, st_in),
-            )
-            rejected = jnp.float32(1.0) - ok.astype(jnp.float32)
-            # a rejected candidate's entropy estimate is an estimate of
-            # garbage (NaN pmf): keep the metric stream finite.  wire is
-            # NOT zeroed — the candidate's exchange really moved bytes.
-            coded = jnp.where(jnp.isfinite(coded), coded, jnp.float32(0.0))
+            with scope("guard"):
+                ok_local = faults_mod.tree_all_finite(
+                    loss, new_params, new_state, ex_state
+                )
+                bad = (~ok_local).astype(jnp.float32)
+                if ex is not None:
+                    # a dropped worker cannot veto the fleet's step (its local
+                    # candidate never entered the aggregate), but it still
+                    # shows up in the nonfinite diagnostic
+                    bad_alive = bad * mask if mask is not None else bad
+                    nonfin_any = jax.lax.psum(bad, axis_name)
+                    ok = jax.lax.psum(bad_alive, axis_name) == 0
+                else:
+                    nonfin_any = bad
+                    ok = bad == 0
+                nonfin = (nonfin_any > 0).astype(jnp.float32)
+                new_params, new_state, ex_state = jax.lax.cond(
+                    ok,
+                    lambda t: (t[0], t[1], t[2]),
+                    lambda t: (t[3], t[4], t[5]),
+                    (new_params, new_state, ex_state, params, opt_state, st_in),
+                )
+                rejected = jnp.float32(1.0) - ok.astype(jnp.float32)
+                # a rejected candidate's entropy estimate is an estimate of
+                # garbage (NaN pmf): keep the metric stream finite.  wire is
+                # NOT zeroed — the candidate's exchange really moved bytes.
+                coded = jnp.where(jnp.isfinite(coded), coded, jnp.float32(0.0))
         metrics = {"loss": loss, "wire_bytes": wire, "param_drift": drift,
                    "coded_bits_est": coded, "rejected": rejected,
                    "nonfinite": nonfin, "alive": alive_m}

@@ -83,6 +83,27 @@ def build_exchange_config(args):
     )
 
 
+class CompileCounter:
+    """Backend compiles while entered, from jax's monitoring events (the
+    event ``bench/harness.CompileStats`` times)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.count = 0
+
+    def _on_duration(self, event, secs, **_):
+        if event == self.EVENT:
+            self.count += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+
+
 class TrainRun(NamedTuple):
     """What one training run is built from (see :func:`build_run`)."""
 
@@ -177,9 +198,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "sync late, overlapping step N's tail exchange "
                          "with step N+1's forward (DESIGN.md §10)")
     ap.add_argument("--profile-dir", default="",
-                    help="emit a jax.profiler trace of the train loop here "
-                         "(named_scope-annotated per exchange bucket; view "
-                         "in TensorBoard/Perfetto — DESIGN.md §10)")
+                    help="emit a jax.profiler trace of the train loop here: "
+                         "one 'train' step span per step with its host phases, "
+                         "the step's stage and exchange-kernel named_scopes "
+                         "inside its HLO (view in TensorBoard/Perfetto — "
+                         "DESIGN.md §10)")
     ap.add_argument("--level-schedule", default="fixed",
                     choices=("fixed", "qada"))
     ap.add_argument("--level-update-every", type=int, default=0,
@@ -322,81 +345,108 @@ def main(argv=None):
     times = []
     fixed_batch = add_modality_stubs(next(pipe), cfg, seed=args.seed)
     # --profile-dir: one jax.profiler trace spanning the whole loop (the
-    # named_scope bucket annotations land inside the step's HLO; closed
+    # step's named scopes land inside its HLO; each loop iteration is a
+    # "train" step span with its phases as host spans inside it; closed
     # right after the last step so the final flush happens before any
     # checkpoint I/O)
     profiler = contextlib.ExitStack()
     profiler.enter_context(profile_trace(args.profile_dir))
+    compiles = profiler.enter_context(CompileCounter())
+    span = jax.profiler.TraceAnnotation
+    recompiles = 0
     for step in range(start_step, args.steps):
-        batch = fixed_batch if args.repeat_batch else add_modality_stubs(
-            next(pipe), cfg, seed=args.seed)
-        t0 = time.time()
-        step_args = [params, opt_state, ex_state, batch,
-                     jax.random.fold_in(key, step)]
-        if needs_fault_step:
-            # the fault schedule is keyed on the TRAIN-LOOP step (not the
-            # optimizer count — a rejected step does not advance count and
-            # a count-keyed fault would re-fire forever)
-            step_args.append(step)
-        params, opt_state, ex_state, metrics = jitted(*step_args)
-        # fence the async dispatch for honest step timing WITHOUT moving
-        # the metrics: device->host transfers (the float() fetches) are
-        # blocking round-trips and are only paid on log steps
-        jax.block_until_ready(metrics["loss"])
-        times.append(time.time() - t0)
-        rejected = False
-        if watchdog is not None:
-            # guard mode pays two scalar fetches per step; the snapshot is
-            # a host copy, taken BEFORE the next jitted call invalidates
-            # the donated output buffers
-            rejected = bool(float(metrics["rejected"]))
-            nonfin = bool(float(metrics["nonfinite"]))
-            if watchdog.observe(step, rejected, nonfin):
-                if isinstance(opt_state, qgenx_opt.QGenXOptState):
-                    print(f"[train] watchdog: optimizer stats at rollback "
-                          f"{qgenx_opt.state_norms(opt_state)}", flush=True)
-                snap_step, trees = watchdog.rollback()
-                params, opt_state, ex_state = jax.device_put(
-                    (trees["params"], trees["opt_state"], trees["ex_state"]),
-                    run.state_sharding)
-                print(f"[train] watchdog: rolled back to the step-"
-                      f"{snap_step} snapshot ({watchdog.summary()})",
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with span("data"):
+                batch = fixed_batch if args.repeat_batch else (
+                    add_modality_stubs(next(pipe), cfg, seed=args.seed))
+            t0 = time.time()
+            with span("dispatch"):
+                step_args = [params, opt_state, ex_state, batch,
+                             jax.random.fold_in(key, step)]
+                if needs_fault_step:
+                    # the fault schedule is keyed on the TRAIN-LOOP step
+                    # (not the optimizer count — a rejected step does not
+                    # advance count and a count-keyed fault would re-fire
+                    # forever)
+                    step_args.append(step)
+                before = compiles.count
+                params, opt_state, ex_state, metrics = jitted(*step_args)
+                # the first call compiles the step; any later compile is
+                # a recompile (state of another type or placement)
+                step_compiles = (compiles.count - before
+                                 if step > start_step else 0)
+                recompiles += step_compiles
+            # fence the async dispatch for honest step timing WITHOUT
+            # moving the metrics: device->host transfers (the float()
+            # fetches) are blocking round-trips and are only paid on log
+            # steps
+            with span("wait"):
+                jax.block_until_ready(metrics["loss"])
+            times.append(time.time() - t0)
+            rejected = False
+            if watchdog is not None:
+                # guard mode pays two scalar fetches per step; the
+                # snapshot is a host copy, taken BEFORE the next jitted
+                # call invalidates the donated output buffers
+                with span("metric fetch"):
+                    rejected = bool(float(metrics["rejected"]))
+                    nonfin = bool(float(metrics["nonfinite"]))
+                with span("watchdog snapshot"):
+                    if watchdog.observe(step, rejected, nonfin):
+                        if isinstance(opt_state, qgenx_opt.QGenXOptState):
+                            print(f"[train] watchdog: optimizer stats at "
+                                  f"rollback "
+                                  f"{qgenx_opt.state_norms(opt_state)}",
+                                  flush=True)
+                        snap_step, trees = watchdog.rollback()
+                        params, opt_state, ex_state = jax.device_put(
+                            (trees["params"], trees["opt_state"],
+                             trees["ex_state"]), run.state_sharding)
+                        print(f"[train] watchdog: rolled back to the step-"
+                              f"{snap_step} snapshot ({watchdog.summary()})",
+                              flush=True)
+                    elif not rejected:
+                        watchdog.record_good(step + 1, {
+                            "params": params, "opt_state": opt_state,
+                            "ex_state": ex_state,
+                        })
+            is_last = step == args.steps - 1
+            log_now = step % args.log_every == 0 or step_compiles > 0
+            if log_now or is_last:
+                with span("metric fetch"):
+                    loss = float(metrics["loss"])
+                    wire = float(metrics["wire_bytes"])
+                    drift = float(metrics["param_drift"])
+                    coded = float(metrics["coded_bits_est"])
+            if log_now:
+                tail = f" drift={drift:.3e}" if args.sync_every > 1 else ""
+                if coded:
+                    tail += f" coded_bits={coded:.3e}"
+                if rejected:
+                    tail += " REJECTED"
+                if needs_fault_step and ex is not None:
+                    alive = float(metrics["alive"])
+                    if alive != n_dev:
+                        tail += f" alive={alive:.0f}/{n_dev}"
+                if step_compiles:
+                    tail += f" RECOMPILED={step_compiles}"
+                print(f"[train] step={step} loss={loss:.4f} "
+                      f"dt={times[-1]*1e3:.0f}ms wire={wire:.3e}B{tail}",
                       flush=True)
-            elif not rejected:
-                watchdog.record_good(step + 1, {
-                    "params": params, "opt_state": opt_state,
-                    "ex_state": ex_state,
-                })
-        is_last = step == args.steps - 1
-        if step % args.log_every == 0 or is_last:
-            loss = float(metrics["loss"])
-            wire = float(metrics["wire_bytes"])
-            drift = float(metrics["param_drift"])
-            coded = float(metrics["coded_bits_est"])
-        if step % args.log_every == 0:
-            tail = f" drift={drift:.3e}" if args.sync_every > 1 else ""
-            if coded:
-                tail += f" coded_bits={coded:.3e}"
-            if rejected:
-                tail += " REJECTED"
-            if needs_fault_step and ex is not None:
-                alive = float(metrics["alive"])
-                if alive != n_dev:
-                    tail += f" alive={alive:.0f}/{n_dev}"
-            print(f"[train] step={step} loss={loss:.4f} "
-                  f"dt={times[-1]*1e3:.0f}ms wire={wire:.3e}B{tail}", flush=True)
-        if args.checkpoint_dir and args.checkpoint_every and (
-            (step + 1) % args.checkpoint_every == 0
-        ):
-            checkpointing.save(
-                args.checkpoint_dir, step + 1,
-                {"params": params, "opt_state": opt_state,
-                 "ex_state": ex_state},
-            )
-            for kind in fault_spec.ckpt_faults_at(step + 1):
-                faults.inject_ckpt_fault(args.checkpoint_dir, step + 1, kind)
-                print(f"[train] fault: injected {kind} into checkpoint "
-                      f"{step + 1}", flush=True)
+            if args.checkpoint_dir and args.checkpoint_every and (
+                (step + 1) % args.checkpoint_every == 0
+            ):
+                with span("checkpoint save"):
+                    checkpointing.save(
+                        args.checkpoint_dir, step + 1,
+                        {"params": params, "opt_state": opt_state,
+                         "ex_state": ex_state},
+                    )
+                    for kind in fault_spec.ckpt_faults_at(step + 1):
+                        faults.inject_ckpt_fault(args.checkpoint_dir,
+                                                 step + 1, kind)
+                        print(f"[train] fault: injected {kind} into "
+                              f"checkpoint {step + 1}", flush=True)
     profiler.close()
     if not times:  # restored checkpoint already at/past --steps: nothing
         # ran, so save NOTHING — a save here would rewind the checkpoint
@@ -420,7 +470,8 @@ def main(argv=None):
         print(f"[train] qada levels={np.round(np.asarray(ex_state.levels), 4)}",
               flush=True)
     med = sorted(times[1:])[len(times[1:]) // 2] if len(times) > 1 else times[0]
-    print(f"[train] done. final_loss={loss:.4f} median_step={med*1e3:.0f}ms")
+    print(f"[train] done. final_loss={loss:.4f} median_step={med*1e3:.0f}ms "
+          f"recompiles={recompiles}")
     return loss
 
 
