@@ -71,8 +71,9 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -271,10 +272,10 @@ def _bracket_select(u: Array, levels: Array):
     """(tau, lo, hi, xi) for normalized magnitudes ``u`` in [0, 1]: the
     bracket index (compare-accumulate over the static interior levels —
     equal to ``clip(searchsorted(levels, u, 'right') - 1, 0, s)``), its
-    endpoints, and the fractional position.  THE single definition of
-    the Definition-1 bracket used by both the leafwise rounding
-    (:func:`_round_indices_select`) and its expectation
-    (:func:`expected_index_pmf`) — the two cannot drift apart."""
+    endpoints, and the fractional position: the Definition-1 bracket of
+    the leafwise rounding (:func:`_round_indices_select`).  Its
+    expectation, :func:`expected_index_pmf`, is summed in one pass
+    without a bracket index; tests hold it to the searchsorted form."""
     s2 = levels.shape[0]
     tau = jnp.zeros(u.shape, jnp.int32)
     for j in range(1, s2 - 1):
@@ -298,38 +299,45 @@ def expected_index_pmf(u: Array, levels: Array) -> Array:
     contributes mass (1-xi) to symbol tau and xi to tau+1 — no PRNG draw
     needed for the expectation.  Returns a [num_symbols] f32 pmf.
 
-    Built from per-symbol masked reductions (the symbol count is static
-    and small) rather than a scatter-add: this runs inside the train
-    step's shard_map, and XLA's SPMD partitioner cannot lower scatter
-    under a partially-manual mesh (the same class of lowering limit that
-    forces ``ModelConfig.unroll_scan`` there).  Each reduction reads only
-    ``u`` and scalar levels — the bracket of :func:`_bracket_select`
-    spelled as two compares per symbol — so no per-coordinate bracket
-    index is materialised: over a model's gradient those intermediates
-    would outgrow the device.
+    One pass brackets each coordinate once (:func:`_bracket_excess`): no
+    scatter-add, which XLA's SPMD partitioner cannot lower under the
+    train step's partially-manual mesh, and no per-coordinate bracket
+    index, which over a model's gradient would outgrow the device.
     """
     lv = levels.astype(jnp.float32)
-    num_symbols = lv.shape[0]
-    top = num_symbols - 2  # last bracket [l_top, l_top+1] holds u == 1
-    u = u.reshape(-1)
+    return _pmf_from_excess(_bracket_excess(u.astype(jnp.float32), lv),
+                            lv, u.size)
 
-    def in_bracket(t):
-        lower = u >= lv[t] if t > 0 else True
-        upper = u < lv[t + 1] if t < top else True
-        return jnp.logical_and(lower, upper)
 
-    def xi(t):
-        return jnp.clip((u - lv[t]) / (lv[t + 1] - lv[t]), 0.0, 1.0)
+def _bracket_excess(u: Array, lv: Array) -> Array:
+    """[num_symbols - 1] sums over every coordinate of ``u``: entry j is
+    the part of its magnitude that lies in bracket j, min(u, l_j+1) -
+    min(u, l_j) — u - l_j inside the bracket, the bracket's width above
+    it, 0 below.  One min, one subtract and one add per level and
+    coordinate; a zero coordinate adds nothing anywhere."""
+    out, below = [], None
+    for j in range(1, lv.shape[0]):
+        m = jnp.minimum(u, lv[j])
+        out.append(jnp.sum(m if below is None else m - below))
+        below = m
+    return jnp.stack(out)
 
-    pmf = []
-    for j in range(num_symbols):
-        mass = jnp.float32(0.0)
-        if j <= top:  # rounded down from bracket j
-            mass += jnp.sum(jnp.where(in_bracket(j), 1.0 - xi(j), 0.0))
-        if j > 0:  # rounded up from bracket j - 1
-            mass += jnp.sum(jnp.where(in_bracket(j - 1), xi(j - 1), 0.0))
-        pmf.append(mass)
-    return jnp.stack(pmf) / u.shape[0]
+
+def _pmf_from_excess(excess: Array, lv: Array, n) -> Array:
+    """The pmf of :func:`expected_index_pmf` from the bracket sums of
+    :func:`_bracket_excess` over ``n`` coordinates.  Bracket j's sum over
+    its width is the expected number of coordinates rounded above level
+    j, A_j (a coordinate inside it rounds up with probability xi, one
+    above it always does), so symbol j holds A_j-1 - A_j, with A_-1 = n
+    and A_top+1 = 0.  Coordinates left out of the sums — zero padding,
+    all-zero buckets — land on symbol 0 through ``n`` alone."""
+    width = lv[1:] - lv[:-1]
+    above = jnp.where(width > 0, excess / jnp.where(width > 0, width, 1.0),
+                      0.0)
+    n = jnp.float32(n)
+    upper = jnp.concatenate([n[None], above])
+    lower = jnp.concatenate([above, jnp.zeros((1,), jnp.float32)])
+    return (upper - lower) / n
 
 
 def theorem2_bits_traced(pmf: Array, d, num_buckets) -> Array:
@@ -349,6 +357,116 @@ def theorem2_bits_traced(pmf: Array, d, num_buckets) -> Array:
     h = -jnp.sum(jnp.where(nz, pmf * jnp.log2(jnp.where(nz, pmf, 1.0)), 0.0))
     d = jnp.float32(d)
     return C_B * jnp.float32(num_buckets) + (1.0 - pmf[0]) * d + (h + 1.0) * d
+
+
+class CodedRun(NamedTuple):
+    """One stretch of the bucket-padded flat buffer as the Theorem-2 pass
+    reads it: whole buckets ``[start, stop)`` holding leaves ``ids``.
+    ``in_place``: one leaf, read where it lies, bucket j of each row
+    being its columns [j*b, (j+1)*b); otherwise packed into [-1, b]."""
+
+    start: int
+    stop: int
+    ids: tuple
+    in_place: bool
+
+
+@functools.lru_cache(maxsize=None)
+def coded_runs(shapes: tuple, offsets: tuple, bucket: int) -> tuple:
+    """Split the leaves at ``offsets`` of a bucket-aligned flat buffer
+    into :class:`CodedRun`s.  A leaf is read in place when it starts on a
+    bucket boundary and has two or more dimensions, the last a multiple of
+    the bucket (so its size is whole buckets too); each maximal stretch of
+    other leaves is one packed run, from the bucket its first leaf starts
+    in to the bucket its last leaf ends in.  Buckets that hold no leaf
+    are all zero and in no run."""
+    runs, pack = [], []
+
+    def close():
+        if pack:
+            lo = offsets[pack[0]] // bucket * bucket
+            end = offsets[pack[-1]] + xplan.size_of(shapes[pack[-1]])
+            runs.append(CodedRun(lo, -(-end // bucket) * bucket,
+                                 tuple(pack), False))
+            pack.clear()
+
+    for i in sorted(range(len(shapes)), key=lambda i: offsets[i]):
+        shape, off = shapes[i], offsets[i]
+        n = xplan.size_of(shape)
+        if n == 0:
+            continue
+        if len(shape) >= 2 and shape[-1] % bucket == 0 and off % bucket == 0:
+            close()
+            runs.append(CodedRun(off, off + n, (i,), True))
+        else:
+            pack.append(i)
+    close()
+    return tuple(runs)
+
+
+def _share_rows(x: Array, axis_size: int, axis_index):
+    """This device's contiguous 1/axis_size share of ``x``'s rows (its
+    first axis after leading unit axes), and a row mask when the shares
+    cannot all be whole: each takes ceil(rows / axis_size) rows, the
+    last ones start early and mask the rows of the share before them."""
+    lead = 0
+    while lead < x.ndim - 2 and x.shape[lead] == 1:
+        lead += 1
+    x = x.reshape(x.shape[lead:])
+    if axis_size == 1:
+        return x, None
+    rows = x.shape[0]
+    take = -(-rows // axis_size)
+    first = axis_index * take
+    start = jnp.minimum(first, rows - take)
+    x = jax.lax.dynamic_slice_in_dim(x, start, take, axis=0)
+    if rows % axis_size == 0:
+        return x, None
+    keep = start + jnp.arange(take) >= first
+    return x, keep.reshape((take,) + (1,) * (x.ndim - 2))
+
+
+def _packed_excess(leaves, offsets, run: CodedRun, bucket: int, lv: Array,
+                   q_norm: float, axis_size: int, axis_index) -> Array:
+    """:func:`_bucket_excess` of a packed run, of which each device packs
+    only its own contiguous share of buckets: the shares are static
+    spans, one ``lax.switch`` branch per position, and differ by at most
+    one bucket."""
+    nb = (run.stop - run.start) // bucket
+
+    def share(k):
+        lo = run.start + k * nb // axis_size * bucket
+        hi = run.start + (k + 1) * nb // axis_size * bucket
+
+        def excess(leaves):
+            if hi == lo:
+                return jnp.zeros((lv.shape[0] - 1,), jnp.float32)
+            x = xplan.pack_span(leaves, offsets, run.ids, lo, hi)
+            return _bucket_excess(x.reshape(-1, bucket), bucket, lv, q_norm,
+                                  None)
+        return excess
+
+    if axis_size == 1:
+        return share(0)(leaves)
+    return jax.lax.switch(axis_index, [share(k) for k in range(axis_size)],
+                          leaves)
+
+
+def _bucket_excess(x: Array, bucket: int, lv: Array, q_norm: float,
+                   keep) -> Array:
+    """:func:`_bracket_excess` over the buckets of ``x``, bucket j of a
+    row being its columns [j*bucket, (j+1)*bucket), each coordinate
+    normalized by its bucket's L^q norm: static lane slices, so a leaf
+    is read where it lies.  Rows ``keep`` masks and zero buckets add
+    nothing."""
+    out = 0.0
+    for j in range(x.shape[-1] // bucket):
+        blk = x[..., j * bucket:(j + 1) * bucket].astype(jnp.float32)
+        norm = bucket_norms(blk, q_norm)
+        live = norm > 0 if keep is None else (norm > 0) & keep
+        inv = jnp.where(live, 1.0 / jnp.where(live, norm, 1.0), 0.0)
+        out = out + _bracket_excess(jnp.abs(blk) * inv[..., None], lv)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -2184,7 +2302,8 @@ class Exchange:
 
     # -- accounting ----------------------------------------------------
 
-    def coded_bits_tree(self, tree, state: ExchangeState) -> Array:
+    def coded_bits_tree(self, tree, state: ExchangeState, axis_name=None,
+                        axis_index=None, gate=None) -> Array:
         """Traced Theorem-2 estimate of the entropy-coded bits ONE worker
         would broadcast for this pytree (CODE o Q with an optimal prefix
         code), under the current level table.
@@ -2203,28 +2322,83 @@ class Exchange:
         would need per-group pmfs against BOTH level tables (its
         dominant big-leaf group is quantized with ``levels_lo``, which a
         single-table estimate would silently misprice).
+
+        One pass reads the tree at the flat buffer's offsets (the
+        compress plan's, or the plain concatenation's without a plan),
+        in :func:`coded_runs`: a leaf that starts on a bucket boundary
+        with a last axis of whole buckets is read where it lies, the
+        other leaves are packed run by run, and buckets that hold no
+        leaf count as zeros without being read.
+
+        ``axis_name`` splits the pass over that axis: each device reads a
+        contiguous share of every run's buckets (its position is
+        ``axis_index``, else ``lax.axis_index``) and one psum of the
+        [num_symbols - 1] bracket sums gives the whole.  It requires a
+        tree replicated over the axis, such as an exchanged mean.
+        ``gate`` (traced bool) runs the pass only where it holds, inside
+        a ``lax.cond`` with the psum outside it, and reads 0.0 elsewhere.
         """
         if self.cfg.compressor != "qgenx":
             return jnp.float32(0.0)
         q = self._hist_quant()
+        b = q.bucket_size
         leaves = jax.tree_util.tree_leaves(tree)
-        if self.cfg.use_plan:
-            # the same (cached) plan the compress path uses: the packed
-            # buffer is already bucket-aligned, so the pad is free — and
-            # bit-identical to the concat+pad it replaces
-            plan = self.compressor.plan_for(leaves, self.cfg, 1, "compress")
-            v2d = plan.pack(leaves).reshape(-1, q.bucket_size)
+        offsets, total, runs = self._coded_layout(leaves)
+        lv = state.levels.astype(jnp.float32)
+        axis_size = 1 if axis_name is None else jax.lax.psum(1, axis_name)
+        if axis_name is not None and axis_index is None:
+            axis_index = jax.lax.axis_index(axis_name)
+
+        def excess(leaves):
+            out = jnp.zeros((lv.shape[0] - 1,), jnp.float32)
+            for run in runs:
+                if run.in_place:
+                    x, keep = _share_rows(leaves[run.ids[0]], axis_size,
+                                          axis_index)
+                    out = out + _bucket_excess(x, b, lv, q.q_norm, keep)
+                else:
+                    out = out + _packed_excess(leaves, offsets, run, b, lv,
+                                               q.q_norm, axis_size,
+                                               axis_index)
+            return out
+
+        if gate is None:
+            sums = excess(leaves)
         else:
-            flat = jnp.concatenate(
-                [l.reshape(-1).astype(jnp.float32) for l in leaves]
-            )
-            v2d, _ = _pad_to_buckets(flat, q.bucket_size)
-        norms = bucket_norms(v2d, q.q_norm)
-        safe = jnp.where(norms > 0, norms, 1.0)
-        u = jnp.clip(jnp.abs(v2d) / safe[:, None], 0.0, 1.0)
-        pmf = expected_index_pmf(u, state.levels)
-        nb = v2d.shape[0]
-        return theorem2_bits_traced(pmf, nb * q.bucket_size, nb)
+            sums = jax.lax.cond(
+                gate, excess,
+                lambda _: jnp.zeros((lv.shape[0] - 1,), jnp.float32), leaves)
+        if axis_name is not None:
+            sums = jax.lax.psum(sums, axis_name)
+        bits = theorem2_bits_traced(_pmf_from_excess(sums, lv, total), total,
+                                    total // b)
+        return bits if gate is None else jnp.where(gate, bits, 0.0)
+
+    def coded_in_place_share(self, tree) -> float:
+        """The share of ``tree``'s coordinates that :meth:`coded_bits_tree`
+        reads in place rather than packing (static; 0.0 when the
+        compressor prices nothing)."""
+        if self.cfg.compressor != "qgenx":
+            return 0.0
+        leaves = jax.tree_util.tree_leaves(tree)
+        n = sum(xplan.size_of(l) for l in leaves)
+        _, _, runs = self._coded_layout(leaves)
+        return sum(r.stop - r.start for r in runs if r.in_place) / max(n, 1)
+
+    def _coded_layout(self, leaves):
+        """(offsets, padded total, :func:`coded_runs`) of the flat buffer
+        the Theorem-2 pass prices: the compress plan's, or without a plan
+        the plain concatenation padded to whole buckets."""
+        b = self._hist_quant().bucket_size
+        shapes = tuple(tuple(l.shape) for l in leaves)
+        if self.cfg.use_plan:
+            plan = self.compressor.plan_for(leaves, self.cfg, 1, "compress")
+            offsets, total = plan.offsets, plan.total
+        else:
+            sizes = [xplan.size_of(s) for s in shapes]
+            offsets = tuple(itertools.accumulate(sizes, initial=0))[:-1]
+            total = -(-sum(sizes) // b) * b
+        return offsets, total, coded_runs(shapes, offsets, b)
 
     def _qada_wire_bytes(self) -> float:
         """The qada schedule psums the [qada_bins] f32 histogram once per

@@ -143,16 +143,7 @@ class ExchangePlan:
         pad (and no second copy of the gradient) is ever needed: the
         result is already bucket/quota aligned per segment.
         """
-        parts, pos = [], 0
-        for i in self.pack_order:
-            off = self.offsets[i]
-            if off > pos:  # previous segment's padding tail
-                parts.append(jnp.zeros((off - pos,), jnp.float32))
-            parts.append(leaves[i].reshape(-1).astype(jnp.float32))
-            pos = off + _size(self.shapes[i])
-        if pos < self.total:
-            parts.append(jnp.zeros((self.total - pos,), jnp.float32))
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        return pack_span(leaves, self.offsets, self.pack_order, 0, self.total)
 
     def unpack(self, flat: Array, leaves) -> list:
         """Flat buffer -> per-leaf arrays (static slices at the plan's
@@ -201,6 +192,28 @@ def size_of(s) -> int:
 
 
 _size = size_of  # internal alias (plan code passes bare shape tuples)
+
+
+def pack_span(leaves, offsets, ids, start: int, stop: int) -> Array:
+    """Coordinates ``[start, stop)`` of the flat f32 buffer that holds
+    leaves ``ids`` (ascending by offset) at ``offsets``, with zeros where
+    no leaf lies: one concatenate.  A leaf that crosses an end of the
+    span gives the part inside it."""
+    parts, pos = [], start
+    for i in ids:
+        lo, hi = max(offsets[i], start), min(offsets[i] + _size(leaves[i]), stop)
+        if lo >= hi:
+            continue
+        if lo > pos:  # a padding tail between leaves
+            parts.append(jnp.zeros((lo - pos,), jnp.float32))
+        flat = leaves[i].reshape(-1)
+        if hi - lo < flat.shape[0]:
+            flat = flat[lo - offsets[i]: hi - offsets[i]]
+        parts.append(flat.astype(jnp.float32))
+        pos = hi
+    if pos < stop:
+        parts.append(jnp.zeros((stop - pos,), jnp.float32))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def leaf_key(leaves) -> tuple:

@@ -462,21 +462,17 @@ def make_train_step(
                 # one worker's GRADIENT broadcasts would cost under CODE o Q
                 # with an optimal prefix code, alongside the fixed-width
                 # wire_bytes actually shipped — per-call x n_grad_calls.
-                # The O(n) pmf pass is gated like the drift probe: under the
-                # local-update regime it only runs on sync steps (its result
-                # would be multiplied by a traced zero otherwise, which XLA
-                # cannot eliminate).
+                # g2 is the exchanged mean, the same on every device, so
+                # each prices a share of its buckets.  The O(n) pass is
+                # gated like the drift probe: under the local-update regime
+                # it only runs on sync steps (its result would be
+                # multiplied by a traced zero otherwise, which XLA cannot
+                # eliminate).
                 if ex.cfg.compressor == "qgenx":
                     n_grad_calls = (st_grad.step - st_in.step).astype(jnp.float32)
-                    if is_sync is None:
-                        coded_per = ex.coded_bits_tree(g2, st_in)
-                    else:
-                        coded_per = jax.lax.cond(
-                            is_sync,
-                            lambda g: ex.coded_bits_tree(g, st_in),
-                            lambda g: jnp.float32(0.0),
-                            g2,
-                        )
+                    coded_per = ex.coded_bits_tree(
+                        g2, st_in, axis_name=axis_name, axis_index=ix,
+                        gate=is_sync)
                     coded = coded_per * n_grad_calls
                 if is_sync is not None:
                     # drift probe: measured (and paid) only on sync steps —

@@ -287,7 +287,8 @@ def main(argv=None):
               f"sync_every={ex_cfg.sync_every} "
               f"recenter_every={ex_cfg.recenter_every} "
               f"plan={ex_cfg.use_plan} "
-              f"num_buckets={ex_cfg.num_buckets} overlap={ex_cfg.overlap}",
+              f"num_buckets={ex_cfg.num_buckets} overlap={ex_cfg.overlap} "
+              f"coded_in_place={ex.coded_in_place_share(params):.1%}",
               flush=True)
     if args.optimizer == "qgenx":
         print(f"[train] qgenx method={args.method}", flush=True)

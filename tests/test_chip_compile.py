@@ -14,8 +14,10 @@ cache is off around these compiles (an entry written for a described chip
 cannot be read back without one).
 """
 
+import dataclasses
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +37,7 @@ from repro.kernels.dequantize import dequantize_blocks
 from repro.kernels.quantize import quantize_blocks
 from repro.kernels.segment_quantize import quantize_dequantize_segments
 from repro.models import transformer as T
+from repro.models.model import build
 
 BUCKET = 512
 K = 4  # workers of the two-phase middle step (the 2x2 host)
@@ -234,3 +237,35 @@ def test_exchange_compiles_on_2x2(topo, n):
         _sds((2,), jnp.uint32, repl))
     text = compiled.as_text()
     assert "all-to-all" in text and "all-gather" in text
+
+
+def test_coded_estimate_split_compiles_on_2x2(topo):
+    """The Theorem-2 estimate of the qwen3-4b-l1 gradient, split over the
+    four chips as the train step runs it: one psum of the bracket sums,
+    and no temporary as large as a quarter of the float32 embedding (it
+    is read where it lies, not repacked)."""
+    cfg = dataclasses.replace(get_config("qwen3-4b"), num_layers=1)
+    shapes = jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0))
+    mesh = Mesh(np.array(topo.devices).reshape(K), ("data",))
+    repl = NamedSharding(mesh, P())
+    ex = make_exchange(ExchangeConfig(
+        compressor="qgenx", quant=_quant(8), mode="two_phase",
+        axis_name="data"))
+    tree, state = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, repl),
+        (shapes, jax.eval_shape(ex.init_state)))
+
+    def f(t, st, ix):
+        return jax.shard_map(
+            lambda t, st, ix: ex.coded_bits_tree(t, st, axis_name="data",
+                                                 axis_index=ix[0]),
+            mesh=mesh, in_specs=(P(), P(), P("data")), out_specs=P(),
+            check_vma=False)(t, st, ix)
+
+    compiled = jax.jit(f).lower(
+        tree, state, _sds((K,), jnp.int32, NamedSharding(mesh, P("data")))
+    ).compile()
+    embed = shapes["embed"]
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        embed.size * embed.dtype.itemsize // K)
+    assert re.search(r"f32\[16\][^ ]* all-reduce\(", compiled.as_text())

@@ -56,6 +56,15 @@ def test_recenter_wire_accounting_8dev():
     assert "ALL OK" in r.stdout
 
 
+def test_coded_estimate_split_4dev():
+    """The Theorem-2 estimate split over 4 devices equals the whole-tree
+    pass on a replicated tree (uneven shares, gated, and through the
+    train step)."""
+    r = _run([os.path.join(ROOT, "tests", "_multidev_coded_split.py")])
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert "ALL OK" in r.stdout
+
+
 def test_fault_tolerance_8dev():
     """Acceptance: guard + fault schedule (NaN@5/worker2, drop@8-10/
     worker3) completes all steps with exactly one rejection, byte-exact
